@@ -149,8 +149,9 @@ def bench(full: bool = True) -> List[Dict]:
     return rows
 
 
-def write_json(rows: List[Dict], path: str = JSON_PATH) -> None:
+def write_json(rows: List[Dict], device: Dict, path: str = JSON_PATH) -> None:
     out = {
+        "device": device,
         "bench": "replay",
         "meta": {"nb": NB, "b": B, "workers": list(WORKERS),
                  "policies": list(POLICIES)},
@@ -161,13 +162,14 @@ def write_json(rows: List[Dict], path: str = JSON_PATH) -> None:
 
 
 def main():
-    from .common import emit
+    from .common import emit, start
+    device = start("bench_replay")
     rows = bench()
     # separate CSV blocks (the numeric rows carry an extra record_ms column)
     emit([r for r in rows if r["bench"] == "sched_overhead"])
     print()
     emit([r for r in rows if r["bench"] == "numeric"])
-    write_json(rows)
+    write_json(rows, device)
     print(f"# wrote {JSON_PATH}")
 
 

@@ -506,8 +506,9 @@ def bench_resources(workers: int, repeats: int = 3) -> Dict:
     }
 
 
-def write_json(rows: List[Dict], path: str = JSON_PATH) -> None:
+def write_json(rows: List[Dict], device: Dict, path: str = JSON_PATH) -> None:
     out = {
+        "device": device,
         "bench": "runtime",
         "meta": {"workers": list(WORKERS), "frame_workers": list(FRAME_WORKERS),
                  "smoke": SMOKE},
@@ -518,7 +519,8 @@ def write_json(rows: List[Dict], path: str = JSON_PATH) -> None:
 
 
 def main():
-    from .common import emit
+    from .common import emit, start
+    device = start("bench_runtime")
     overlap_rows = bench(workers=2 if SMOKE else 4)
     emit(overlap_rows)
     print()
@@ -543,7 +545,8 @@ def main():
     resource_rows = [bench_resources(w) for w in FRAME_WORKERS]
     emit(resource_rows)
     write_json(overlap_rows + reuse_rows + trace_rows + frame_rows
-               + victim_rows + compiled_rows + async_rows + resource_rows)
+               + victim_rows + compiled_rows + async_rows + resource_rows,
+               device)
     print(f"# wrote {JSON_PATH}")
 
 

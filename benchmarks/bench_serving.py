@@ -499,8 +499,9 @@ def bench() -> List[Dict]:
     return rows
 
 
-def write_json(rows: List[Dict], path: str = JSON_PATH) -> None:
+def write_json(rows: List[Dict], device: Dict, path: str = JSON_PATH) -> None:
     out = {
+        "device": device,
         "bench": "serving",
         "meta": {"arch": ARCH, "batch": BATCH, "prompt": PROMPT,
                  "steps": STEPS, "workers": list(WORKERS),
@@ -537,7 +538,8 @@ def write_trace_json(rows: List[Dict], path: str = TRACE_PATH) -> None:
 
 
 def main():
-    from .common import emit
+    from .common import emit, start
+    device = start("bench_serving")
 
     rows = bench()
     write_trace_json(rows)
@@ -550,7 +552,7 @@ def main():
     emit([r for r in rows if r["bench"] == "serving_poisson"])
     print()
     emit([r for r in rows if r["bench"] == "serving_procs"])
-    write_json(rows)
+    write_json(rows, device)
     print(f"# wrote {JSON_PATH}")
 
 

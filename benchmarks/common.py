@@ -5,6 +5,7 @@ from __future__ import annotations
 from typing import Dict, List
 
 from repro.core import Simulator
+from repro.device import describe_device, enable_compile_cache
 from repro.linalg.dist import build_dist_cholesky_graph, build_dist_panel_graph
 from repro.linalg.tiles import CostModel
 
@@ -31,6 +32,15 @@ def run(graph, workers: int, ranks: int, *, policy="hybrid", mode="gang",
         seed=0):
     sim = Simulator(workers, ranks=ranks, policy=policy, mode=mode, seed=seed)
     return sim.run(graph)
+
+
+def start(name: str) -> Dict:
+    """Entry-point set-up shared by the benchmarks: place the compile cache
+    and name the device every printed result comes from."""
+    enable_compile_cache()
+    dev = describe_device()
+    print(f"# {name} on {dev}")
+    return dev.as_dict()
 
 
 def emit(rows: List[Dict], header: bool = True) -> None:

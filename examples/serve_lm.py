@@ -1,7 +1,10 @@
 """Serving driver: batched prefill + decode with a KV cache.
 
-Loads (or initializes) a small model, prefills a batch of prompts, then
-decodes N tokens per request.  Three decode schedulers:
+Initializes a model from ``PRNGKey(0)``, prefills a batch of prompts, then
+decodes N tokens per request.  By default the model is the architecture's
+reduced CPU configuration; ``--layers N`` serves it at its published widths
+and dtype instead, cut only in depth to N layers (the size for one chip).
+Three decode schedulers:
 
 * ``jit``     — the original monolithic jitted decode loop (no task graph);
 * ``dynamic`` — each decode step is a task graph (per-shard decode/sample
@@ -21,9 +24,11 @@ request's token budget and warm pool replays per batch shape.
 processes (:mod:`repro.mp`), each hosting its own executor pool; children
 rebuild the model from the same seed via :func:`make_serving_fns` and
 adopt parent-seeded recordings through ``--cache-dir``, so the sharded
-token streams stay bit-identical to single-process serving.
+token streams stay bit-identical to single-process serving.  A chip
+belongs to one process, so on a TPU ``--procs`` is refused.
 
 Run:  PYTHONPATH=src python examples/serve_lm.py --tokens 32 --scheduler pool
+      PYTHONPATH=src python examples/serve_lm.py --layers 8 --prompt-len 512
       PYTHONPATH=src python examples/serve_lm.py --arrivals poisson \
           --rate 100 --requests 12 --scheduler pool
       PYTHONPATH=src python examples/serve_lm.py --arrivals poisson \
@@ -31,6 +36,7 @@ Run:  PYTHONPATH=src python examples/serve_lm.py --tokens 32 --scheduler pool
 """
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -38,18 +44,28 @@ import jax.numpy as jnp
 
 import repro
 from repro.configs import get_config
+from repro.device import describe_device, enable_compile_cache
 from repro.models import (build_decode_graph, decode_step, greedy_sample,
                           init_params, make_decode_state, prefill)
 from repro.replay import GraphCache
 
 
-def make_serving_fns(arch="qwen3-14b", prompt_len=64, tokens=32):
+def serving_config(arch, layers=0):
+    """The served configuration: published widths and dtype cut to
+    ``layers`` layers, or the reduced CPU configuration when ``layers`` is
+    0."""
+    if layers:
+        return dataclasses.replace(get_config(arch), n_layers=layers).validate()
+    return get_config(arch).reduced()
+
+
+def make_serving_fns(arch="qwen3-14b", prompt_len=64, tokens=32, layers=0):
     """Engine-fns factory for ``--procs``: worker processes re-import this
     by reference (``serve_lm:make_serving_fns``) and rebuild the exact
-    parent model — same reduced config, same ``PRNGKey(0)`` params, same
-    jitted step fns — so sharded token streams stay bit-identical to
+    parent model — same config, same ``PRNGKey(0)`` params, same jitted
+    step fns — so sharded token streams stay bit-identical to
     single-process serving."""
-    cfg = get_config(arch).reduced()
+    cfg = serving_config(arch, layers)
     params = init_params(cfg, jax.random.PRNGKey(0))
     max_len = prompt_len + tokens + 1
     prefill_fn = jax.jit(
@@ -90,7 +106,7 @@ def serve_poisson(args, cfg, params, prefill_fn, decode_fn):
             "procs": args.procs,
             "fns_ref": ("serve_lm:make_serving_fns",
                         {"arch": args.arch, "prompt_len": args.prompt_len,
-                         "tokens": args.tokens}),
+                         "tokens": args.tokens, "layers": args.layers}),
         }
     with repro.Session(args.workers, scheduler=args.scheduler,
                        cache=cache_store, trace=bool(args.trace),
@@ -135,6 +151,9 @@ def serve_poisson(args, cfg, params, prefill_fn, decode_fn):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="serve the published widths and dtype cut to N "
+                         "layers (default 0: the reduced CPU configuration)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--tokens", type=int, default=32)
@@ -182,7 +201,9 @@ def main():
     if args.procs and args.trace:
         ap.error("--trace is per-process; not supported with --procs")
 
-    cfg = get_config(args.arch).reduced()
+    enable_compile_cache()
+    print(f"device: {describe_device()}")
+    cfg = serving_config(args.arch, args.layers)
     params = init_params(cfg, jax.random.PRNGKey(0))
     max_len = args.prompt_len + args.tokens + 1
 

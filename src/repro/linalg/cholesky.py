@@ -179,6 +179,10 @@ def reference_cholesky(a: jnp.ndarray) -> jnp.ndarray:
 
 
 def random_spd(n: int, seed: int = 0, dtype=jnp.float64) -> jnp.ndarray:
+    """A well-conditioned SPD matrix ``M Mᵀ + n I`` from ``seed``, built in
+    float64 on the host and handed over as ``dtype``.  Without x64 enabled
+    JAX keeps float64 as float32, so a caller that needs float32 on every
+    configuration asks for it."""
     import numpy as np
 
     rng = np.random.default_rng(seed)

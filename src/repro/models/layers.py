@@ -21,9 +21,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from .compat import shard_map
+from jax import lax, shard_map
 
 # logical axis vocabulary (mapped to mesh axes in repro.sharding.rules)
 LOGICAL_AXES = ("batch", "seq", "embed", "heads", "kv_heads", "ff", "vocab",
@@ -37,7 +35,10 @@ Params = Dict[str, Any]        # nested dict: leaf = jnp.ndarray
 # spec/materialize machinery
 # ---------------------------------------------------------------------------
 def materialize(spec: Spec, key: jax.Array, dtype, scale_rule=None) -> Params:
-    """Initialize a param tree from a spec tree (trunc-normal fan-in)."""
+    """Initialize a param tree from a spec tree: norm scales (``ln*``,
+    ``*norm``, ``gamma*``, ``scale``) at one, whatever their rank once
+    stacked; other matrices trunc-normal with fan-in scaling; other
+    vectors at zero."""
     leaves = []
 
     def _walk(s, path):
@@ -50,12 +51,13 @@ def materialize(spec: Spec, key: jax.Array, dtype, scale_rule=None) -> Params:
     keys = jax.random.split(key, max(1, len(leaves)))
     out: Dict = {}
     for (path, (shape, axes)), k in zip(leaves, keys):
-        if len(shape) >= 2:
+        name = path[-1]
+        if name.startswith(("ln", "gamma")) or name.endswith("norm") or name == "scale":
+            v = jnp.ones(shape, dtype)
+        elif len(shape) >= 2:
             fan_in = shape[-2] if len(shape) == 2 else math.prod(shape[:-1])
             std = 1.0 / math.sqrt(fan_in)
             v = (jax.random.truncated_normal(k, -2.0, 2.0, shape, jnp.float32) * std).astype(dtype)
-        elif path[-1].startswith(("norm", "gamma")) or path[-1] in ("scale",):
-            v = jnp.ones(shape, dtype)
         else:
             v = jnp.zeros(shape, dtype)
         node = out

@@ -18,14 +18,13 @@ Entry points (all pure; jit/shard them from repro.launch):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from .compat import shard_map
 
 from . import layers as L
 from . import ssm as S
@@ -92,7 +91,11 @@ def model_spec(cfg: ModelConfig) -> Dict:
     return spec
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key: jax.Array):
+    """Random parameters for ``cfg``.  Jitted, so each leaf is drawn and
+    cast on the device without a float32 copy of the whole tree: at full
+    width those copies alone would not fit beside the weights."""
     return L.materialize(model_spec(cfg), key, cfg.jdtype)
 
 
@@ -227,9 +230,6 @@ def sharded_ce_loss(h: jnp.ndarray, wout: jnp.ndarray, labels: jnp.ndarray,
             cnt = cnt + jnp.sum(mask)
             return (num, cnt), None
 
-        # rank-1 (1,) carries: scalar carries become scalar autodiff
-        # residuals at the shard_map boundary, which older jax fails to
-        # promote to rank 1 (fixed upstream; harmless on new jax)
         (num, cnt), _ = lax.scan(
             jax.checkpoint(chunk, policy=jax.checkpoint_policies.nothing_saveable),
             (jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32)),
@@ -404,9 +404,12 @@ def _encode(params, cfg: ModelConfig, enc_input, ctx, *, remat=True):
 
 
 def logits_from_hidden(params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
+    """Logits over the real vocabulary: the columns that pad it to
+    :func:`padded_vocab` hold random weights and name no token, so they
+    are cut off before any sampler sees them."""
     wout = params["unembed"]["out"] if "unembed" in params \
         else params["embed"]["table"].T
-    return h @ wout
+    return (h @ wout)[..., :cfg.vocab_size]
 
 
 def loss_fn(params, cfg: ModelConfig, batch, ctx=None, *, remat: bool = True):

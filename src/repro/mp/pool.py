@@ -39,6 +39,7 @@ import pickle
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
+from ..device import refuse_child_processes
 from .futures import RunFuture, WorkerDied
 
 __all__ = ["ProcessPool", "WorkerSpec", "callable_ref", "resolve_ref"]
@@ -168,6 +169,7 @@ class ProcessPool:
                  name: str = "repro-mp", start_timeout: float = 120.0):
         if procs < 1:
             raise ValueError(f"a process pool needs >= 1 worker, got {procs}")
+        refuse_child_processes(f"ProcessPool({procs})")
         self.spec = spec if spec is not None else WorkerSpec()
         self.n_procs = procs
         self.name = name

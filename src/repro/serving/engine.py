@@ -48,6 +48,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from ..api.graph import Graph
 from ..core.taskgraph import Channel
+from ..device import refuse_child_processes
 from .metrics import RequestRecord, ServingReport
 from .request import Request, RequestState
 
@@ -110,7 +111,8 @@ class ContinuousBatchingEngine:
         by exactly this many seconds.
     procs / fns_ref:
         ``procs=N`` shards :meth:`run` across ``N`` worker *processes*
-        (the session's :class:`~repro.mp.ProcessPool`): requests route by
+        (the session's :class:`~repro.mp.ProcessPool`), refused at once
+        where this process computes on a TPU: requests route by
         ``rid % N`` to child-local engines, each with its own interpreter
         — no GIL sharing — and per-request streams stay bit-identical
         because every request decodes against its own KV cache regardless
@@ -149,6 +151,7 @@ class ContinuousBatchingEngine:
                     "procs=N needs fns_ref: child processes rebuild the "
                     "engine fns from a module-level factory reference "
                     "(callables do not cross a spawn boundary)")
+            refuse_child_processes(f"ContinuousBatchingEngine(procs={procs})")
         capacity = (2 * max_batch if admission_capacity is None
                     else admission_capacity)
         if capacity < 1:
@@ -215,7 +218,8 @@ class ContinuousBatchingEngine:
         if request.rid in self._records:
             raise ValueError(f"duplicate request id {request.rid}")
         self._records[request.rid] = RequestRecord(
-            rid=request.rid, arrival_s=request.arrival_s)
+            rid=request.rid, arrival_s=request.arrival_s,
+            submitted_s=self._now())
         deadline = (None if timeout is None
                     else time.monotonic() + timeout)
         while not self._admission.try_send(request):

@@ -23,6 +23,7 @@ class RequestRecord:
 
     rid: int
     arrival_s: float
+    submitted_s: float = 0.0      # entered the admission queue
     admitted_s: float = 0.0       # left the admission queue (prefill start)
     first_token_s: float = 0.0    # prefill done, first token out
     done_s: float = 0.0
@@ -34,6 +35,13 @@ class RequestRecord:
         """Time-to-first-token: arrival -> first generated token (includes
         any admission-queue wait — that is the point)."""
         return self.first_token_s - self.arrival_s
+
+    @property
+    def submit_lag_s(self) -> float:
+        """How long after it was due the request entered the admission
+        queue: a late load generator (or a full queue) shows here, not as
+        a fast server."""
+        return self.submitted_s - self.arrival_s
 
     @property
     def queue_wait_s(self) -> float:

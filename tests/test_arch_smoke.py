@@ -11,11 +11,12 @@ from repro.configs import ARCHS, get_config
 from repro.models import (
     decode_step,
     forward,
+    greedy_sample,
     init_params,
     loss_fn,
     prefill,
 )
-from repro.models.lm import padded_vocab
+from repro.models.lm import logits_from_hidden, padded_vocab
 
 B, S = 2, 64
 
@@ -110,3 +111,22 @@ def test_decode_matches_forward(arch):
         np.asarray(logits_dec[:, 0], dtype=np.float32),
         np.asarray(logits_full[:, s0], dtype=np.float32),
         rtol=2e-2, atol=2e-2)
+
+
+def test_padded_vocab_is_never_sampled():
+    """The vocabulary is padded to a multiple of 256 with columns that name
+    no token; logits stop at the real vocabulary, so greedy sampling can
+    only return real ids."""
+    cfg = get_config("qwen3-14b").reduced(vocab_size=1000)
+    assert padded_vocab(cfg) > cfg.vocab_size
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    batch = make_batch(cfg, jax.random.PRNGKey(1))
+    logits = logits_from_hidden(params, cfg,
+                                jax.jit(lambda p, b: forward(p, cfg, b, None))(
+                                    params, batch))
+    assert logits.shape == (B, S, cfg.vocab_size)
+    ids = greedy_sample(logits.reshape(B * S, 1, cfg.vocab_size))
+    assert int(ids.max()) < cfg.vocab_size
+    _, last = jax.jit(lambda p, b: prefill(p, cfg, b, None, max_len=S + 1))(
+        params, batch)
+    assert int(greedy_sample(last).max()) < cfg.vocab_size

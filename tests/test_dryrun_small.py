@@ -28,10 +28,11 @@ from repro.models import lm
 from repro.sharding.rules import make_ctx
 from repro.launch.shapes import input_specs
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_debug_mesh
 from repro.train.steps import StepConfig, make_train_step
 from repro.optim.adamw import AdamWConfig
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_debug_mesh(4, 2)
 cfg = get_config("deepseek-67b").reduced(n_layers=3, d_model=128, vocab_size=1024,
                                          n_heads=4, n_kv_heads=2, head_dim=32,
                                          d_ff=256, dtype="bfloat16")

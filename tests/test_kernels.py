@@ -124,3 +124,13 @@ def test_ssd_ref_consistent_with_model_ssd():
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(s_model), np.asarray(s_k),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_kernel_mode_must_be_named():
+    """No mode is chosen from the device: an unnamed or unknown mode is an
+    error, never a silent switch to the reference."""
+    a = jnp.ones((8, 8), jnp.float32)
+    with pytest.raises(TypeError):
+        ops.tile_matmul(a, a)
+    with pytest.raises(ValueError, match="unknown kernel mode"):
+        ops.tile_matmul(a, a, mode="auto")
