@@ -436,8 +436,8 @@ def bench_procs(setup, procs: int, workers: int, rate: float) -> Dict:
 
 def _traced_window(setup, workers: int):
     """A short loaded burst with the flight recorder on — a separate drive
-    so tracing overhead never pollutes the measured rows.  The engine keeps
-    the most heavily loaded step's trace (the steady-state window)."""
+    so tracing overhead never pollutes the measured rows.  The engine
+    reports the session recorder's window: every step of the burst."""
     report = _drive(setup, workers, "pool", SERVE_BATCH,
                     _workload(setup, RATES[-1], seed=1,
                               n=min(SERVE_REQUESTS, 6)),
@@ -518,7 +518,7 @@ def write_json(rows: List[Dict], device: Dict, path: str = JSON_PATH) -> None:
 
 
 def write_trace_json(rows: List[Dict], path: str = TRACE_PATH) -> None:
-    """Export the widest worker-count traced step as Perfetto JSON and
+    """Export the widest worker-count traced window as Perfetto JSON and
     schema-validate it (the CI bench-smoke artifact)."""
     from repro.obs import validate_trace_json, write_trace
 

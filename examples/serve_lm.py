@@ -143,7 +143,8 @@ def serve_poisson(args, cfg, params, prefill_fn, decode_fn):
                            "scheduler": args.scheduler,
                            "arrivals": "poisson"})
         m = report.trace.metrics()
-        print(f"trace:   {args.trace} (most loaded step, dispatch overhead "
+        print(f"trace:   {args.trace} (every step of the session's window, "
+              f"dispatch overhead "
               f"{m['dispatch_overhead_fraction']:.1%}, "
               "open in https://ui.perfetto.dev)")
 
@@ -167,8 +168,8 @@ def main():
                     help="on-disk GraphCache dir (pool): recordings persist "
                          "across processes / ship to replicas")
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="serve with the flight recorder on and export the "
-                         "last decode step as Perfetto JSON here "
+                    help="serve with the flight recorder on and export every "
+                         "decode step it holds as Perfetto JSON here "
                          "(open in https://ui.perfetto.dev)")
     ap.add_argument("--arrivals", choices=("batch", "poisson"),
                     default="batch",
@@ -258,24 +259,26 @@ def main():
                        if args.cache_dir and args.scheduler == "pool" else None)
         session = repro.Session(args.workers, scheduler=args.scheduler,
                                 cache=cache_store, trace=bool(args.trace))
-        report = None
         with session:
             t0 = time.perf_counter()
             for _ in range(args.tokens - 1):
                 g = build_decode_graph(state, decode_fn)
-                report = session.run(g)
+                session.run(g)
             state.step_tokens.block_until_ready()
             t_decode = time.perf_counter() - t0
             gen = state.tokens()
             if args.scheduler == "pool":
                 for ckey, stats in session.pool.describe().items():
                     print(f"pool[{ckey[:20]}…]: {stats}")
-        if args.trace and report is not None and report.trace is not None:
+            # every decode step's events: the session recorder's window
+            window = session.trace_window()
+        if args.trace and window is not None and window.events:
             from repro.obs import write_trace
-            write_trace(report.trace, args.trace,
+            trace = window.assemble()
+            write_trace(trace, args.trace,
                         extra={"workers": args.workers, "arch": cfg.name,
                                "scheduler": args.scheduler})
-            m = report.trace.metrics()
+            m = trace.metrics()
             print(f"trace:   {args.trace} "
                   f"(dispatch overhead {m['dispatch_overhead_fraction']:.1%}, "
                   f"open in https://ui.perfetto.dev)")

@@ -49,6 +49,20 @@ Scheduler semantics
     contention, bit-identical results.  A true miss records this run and
     compiles the next; a compiled plan that stalls (stale shape) falls
     back to replay for that run.
+
+Tracing
+-------
+
+``Session(trace=True)`` gives the session one
+:class:`~repro.obs.FlightRecorder` (``session.recorder``) that every
+executor it builds writes to — the dynamic runtime, the replay executors
+and the pool's executors.  Each run's :attr:`RunReport.trace` is assembled
+from that run's events; :meth:`Session.run` adds its own host phases
+(``session.run`` enclosing ``session.plan`` and ``session.execute``), and
+a caller may add its own (the serving engine's ``engine.*``).
+:meth:`Session.trace_window` hands out the raw events of any stretch
+between two marks (:meth:`Session.trace_mark`).  Without tracing,
+``session.recorder`` is the no-op :data:`~repro.obs.NULL_RECORDER`.
 """
 
 from __future__ import annotations
@@ -60,6 +74,7 @@ from typing import Any, Dict, Optional, Union
 
 from ..core.policies import resolve as resolve_policy
 from ..core.taskgraph import TaskGraph
+from ..obs.recorder import NULL_RECORDER, FlightRecorder
 
 __all__ = ["Plan", "PlanError", "RunReport", "Session"]
 
@@ -210,6 +225,12 @@ class Session:
         self.allow_remap = allow_remap
         self.record_default = record
         self.trace = trace
+        #: the session's flight recorder, shared by every executor it builds
+        #: (the no-op singleton without tracing; dropped on close).  Its
+        #: rings hold several runs: one factorization of 40x40 tiles writes
+        #: ~18k events on a worker's ring
+        self.recorder = (FlightRecorder(workers, capacity=1 << 17) if trace
+                         else NULL_RECORDER)
         self.shared_cores = shared_cores
         self.stall_timeout = stall_timeout
         self.block_poll = block_poll
@@ -248,6 +269,7 @@ class Session:
             runtime, self._runtime = self._runtime, None
             core, self._core = self._core, None
             mp_pool, self._mp_pool = self._mp_pool, None
+            self.recorder = NULL_RECORDER
         if mp_pool is not None:
             mp_pool.shutdown()
         for ex in executors:
@@ -298,7 +320,7 @@ class Session:
                 self._runtime = Runtime(
                     self.workers, policy=self.policy,
                     gang_default=self.gang_default, seed=self.seed,
-                    trace=self.trace, core=self._leased_core())
+                    trace=self.recorder, core=self._leased_core())
             return self._runtime
 
     def _replay_executor(self, recording):
@@ -314,7 +336,7 @@ class Session:
             if ex is None:
                 ex = ReplayExecutor(
                     recording, stall_timeout=self.stall_timeout,
-                    check_digest=False, trace=self.trace,
+                    check_digest=False, trace=self.recorder,
                     core=self._leased_core())
                 ex.start()
                 self._executors[recording.digest] = ex
@@ -329,9 +351,27 @@ class Session:
                 kwargs.setdefault("allow_remap", self.allow_remap)
                 kwargs.setdefault("stall_timeout", self.stall_timeout)
                 kwargs.setdefault("shared_cores", self.shared_cores)
-                kwargs.setdefault("trace", self.trace)
+                kwargs.setdefault("trace", self.recorder)
                 self._pool = ReplayPool(self.cache, **kwargs)
             return self._pool
+
+    # ------------------------------------------------------------------
+    # the flight recorder's window
+    def trace_mark(self):
+        """A position in the session recorder's rings, to pass to
+        :meth:`trace_window` (None without tracing)."""
+        return self.recorder.mark() if self.recorder.enabled else None
+
+    def trace_window(self, since=None, until=None):
+        """The raw recorder events between two marks (:meth:`trace_mark`;
+        default: the recorder's start, and now) as a
+        :class:`~repro.obs.Window` — every ring's surviving events, sorted
+        by time, with the count ring overflow overwrote; ``.assemble()``
+        gives the :class:`~repro.obs.trace.RuntimeTrace`.  None without
+        tracing."""
+        if not self.recorder.enabled:
+            return None
+        return self.recorder.window(since, until)
 
     @property
     def pool(self):
@@ -525,13 +565,18 @@ class Session:
         returns a :class:`RunReport`.  ``key`` forwards a precomputed
         :class:`~repro.replay.GraphKey` to :meth:`plan` (and, for pool
         sessions, to the pool) so steady-state loops skip hashing."""
+        if plan is None and graph is None:
+            raise TypeError("run() needs a graph or a plan")
+        rec = self.recorder
+        rec.phase_begin("session.run")
+        rec.phase_begin("session.plan")
         if plan is None:
-            if graph is None:
-                raise TypeError("run() needs a graph or a plan")
             plan = self.plan(graph, record=record, key=key)
         tg = self._as_taskgraph(graph) if graph is not None else plan.graph
+        rec.phase_end("session.plan")
         with self._lock:
             self._require_open()
+            rec.phase_begin("session.execute")
             t0 = time.perf_counter()
             if plan.mode == "pool":
                 report = self._run_pool(plan, tg, timeout)
@@ -544,7 +589,9 @@ class Session:
             else:
                 raise PlanError(f"unknown plan mode {plan.mode!r}")
             report.wall_s = time.perf_counter() - t0
-            return report
+            rec.phase_end("session.execute")
+        rec.phase_end("session.run")
+        return report
 
     def execute(self, plan: Plan, *, timeout: float = 300.0) -> RunReport:
         """Alias: run a prepared plan against its own graph."""

@@ -70,7 +70,7 @@ class Runtime:
         seed: int = 0,
         steal_backoff: float = 20e-6,
         block_poll: float = 0.05,
-        trace: bool = False,
+        trace: Any = False,
         core: Optional[ExecutorCore] = None,
     ):
         if core is not None and core.n_workers != n_workers:
@@ -83,7 +83,6 @@ class Runtime:
         self.seed = seed
         self.steal_backoff = steal_backoff
         self.block_poll = block_poll
-        self.trace_enabled = trace
 
         self._core = core if core is not None else ExecutorCore(
             n_workers, block_poll=block_poll, name="repro-worker")
@@ -91,6 +90,7 @@ class Runtime:
         self._dispatch = DynamicDispatch(
             n_workers, policy=policy, gang_default=gang_default, seed=seed,
             steal_backoff=steal_backoff, trace=trace)
+        self.trace_enabled = self._dispatch.trace_enabled
         #: assembled :class:`~repro.obs.trace.RuntimeTrace` of the most
         #: recent traced run (None with ``trace=False``)
         self.last_trace = None

@@ -64,6 +64,10 @@ EV_RUN_AHEAD = "run_ahead"            # a=tid
 EV_RESOURCE_ACQUIRE = "resource_acquire"  # a=tid, b=n_res   label=task name
 EV_RESOURCE_WAIT = "resource_wait"    # a=tid (task deferred on contention)
 EV_RESOURCE_RELEASE = "resource_release"  # a=tid, b=n_res
+# host phases of the caller (engine step, Session.run): begin/end pairs on
+# the external ring, a=thread ident, label=constant phase name
+EV_PHASE_BEGIN = "phase_begin"
+EV_PHASE_END = "phase_end"
 
 EVENT_KINDS = frozenset({
     EV_TASK_START, EV_TASK_END, EV_STEAL_ATTEMPT, EV_STEAL_HIT,
@@ -72,6 +76,7 @@ EVENT_KINDS = frozenset({
     EV_BLOCK, EV_UNBLOCK, EV_DEADLOCK_POLL, EV_PARK, EV_WAKE,
     EV_REPLAY_FALLBACK, EV_REPLAY_STALL, EV_REPLAY_SKIP, EV_RUN_AHEAD,
     EV_RESOURCE_ACQUIRE, EV_RESOURCE_WAIT, EV_RESOURCE_RELEASE,
+    EV_PHASE_BEGIN, EV_PHASE_END,
 })
 
 

@@ -79,7 +79,7 @@ from ..core.tracing import (
     EV_UNBLOCK,
     EV_WAKE,
 )
-from ..obs.recorder import NULL_RECORDER, FlightRecorder
+from ..obs.recorder import recorder_for
 from ..resources.arbiter import ResourceArbiter
 from .core import DispatchStrategy, ExecutorCore, GangRegion
 
@@ -111,7 +111,7 @@ class DynamicDispatch(DispatchStrategy):
         gang_default: bool = True,
         seed: int = 0,
         steal_backoff: float = 20e-6,
-        trace: bool = False,
+        trace: Any = False,
     ):
         self.core: Optional[ExecutorCore] = None
         self.n_workers = n_workers
@@ -119,10 +119,11 @@ class DynamicDispatch(DispatchStrategy):
         self.gang_default = gang_default
         self.seed = seed
         self.steal_backoff = steal_backoff
-        self.trace_enabled = trace
-        # flight recorder: hot paths call emit unconditionally — with
-        # tracing off this is the no-op singleton (one attribute call)
-        self.recorder = FlightRecorder(n_workers) if trace else NULL_RECORDER
+        # flight recorder (``trace``: a bool, or a session's recorder to
+        # share): hot paths call emit unconditionally — with tracing off
+        # this is the no-op singleton (one attribute call)
+        self.recorder = recorder_for(trace, n_workers)
+        self.trace_enabled = self.recorder.enabled
 
         self._fork_lock = threading.Lock()          # the paper's fork-phase lock
         self.gang_state = GangState(n_workers)

@@ -89,7 +89,7 @@ from ..core.tracing import (
     EV_UNBLOCK,
     EV_WAKE,
 )
-from ..obs.recorder import NULL_RECORDER, FlightRecorder
+from ..obs.recorder import recorder_for
 from ..resources.arbiter import ResourceArbiter
 from .core import DispatchStrategy, ExecutorCore, GangRegion
 
@@ -107,14 +107,13 @@ class ReplayDispatch(DispatchStrategy):
     _RUN_AHEAD_WINDOW = 32
 
     def __init__(self, recording: "Recording", *, stall_timeout: float = 1e-3,
-                 trace: bool = False):
+                 trace: Any = False):
         self.core: Optional[ExecutorCore] = None
         self.recording = recording
         self.n_workers = recording.n_workers
         self.stall_timeout = stall_timeout
-        self.trace_enabled = trace
-        self.recorder = (FlightRecorder(recording.n_workers) if trace
-                         else NULL_RECORDER)
+        self.recorder = recorder_for(trace, recording.n_workers)
+        self.trace_enabled = self.recorder.enabled
 
         n = self.n_workers
         self._orders = [list(o) for o in recording.worker_orders]

@@ -93,6 +93,7 @@ def to_perfetto(trace: RuntimeTrace, *,
         "n_workers": trace.n_workers,
         "counters": dict(trace.counters),
         "dropped": trace.dropped,
+        "t_base": trace.t_base,
         "metrics": trace.metrics(),
     }
     if extra:
@@ -128,6 +129,7 @@ def load_trace(obj: Any) -> RuntimeTrace:
     rt = RuntimeTrace(int(other.get("n_workers", 1)))
     rt.counters = {k: int(v) for k, v in other.get("counters", {}).items()}
     rt.dropped = int(other.get("dropped", 0))
+    rt.t_base = other.get("t_base")
     metrics = other.get("metrics")
     if isinstance(metrics, dict):
         # JSON stringifies the per-worker histograms' int keys

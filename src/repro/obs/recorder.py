@@ -7,16 +7,23 @@ must be cheap enough to leave on):
   no lock is needed on the hot path: a ring append is one ``perf_counter``
   call, one tuple pack, one CPython-atomic list store and an int add.
   Events emitted from *non-worker* threads (a channel send from outside
-  the pool, a background re-record) go to one extra "external" ring,
-  guarded by a small lock (those paths are rare and never hot).
+  the pool, a background re-record, the caller's host phases) go to one
+  extra "external" ring, guarded by a small lock (those paths are rare
+  and never hot).
 * **bounded memory** — each ring holds ``capacity`` events; older events
   are overwritten and counted as dropped (surfaced on the assembled
-  :class:`~repro.obs.trace.RuntimeTrace`).
+  :class:`~repro.obs.trace.RuntimeTrace` and on every :class:`Window`).
+* **one recorder per session** — a traced :class:`~repro.api.Session`
+  owns one recorder that every executor it builds writes to.  A run does
+  not reset the rings: ``begin_run`` marks where the run starts, a run's
+  trace is assembled from the events after that mark, and
+  :meth:`FlightRecorder.window` hands out any stretch between two marks.
 * **near-zero cost when off** — executors hold :data:`NULL_RECORDER`, a
   module-level singleton whose ``emit`` does nothing.  The hot loops do
   ``self.recorder.emit(...)`` unconditionally: no branch, one attribute
   call.  The signature is positional and fixed (no ``*args``) so a no-op
-  emit allocates nothing — tested in ``tests/test_obs.py``.
+  emit allocates nothing — tested in ``tests/test_obs.py``.  Phase labels
+  are constant strings for the same reason.
 
 Recorders register in a ``WeakSet`` so the test suite can assert no trace
 buffer outlives its session (``live_recorders``).
@@ -27,15 +34,22 @@ from __future__ import annotations
 import threading
 import weakref
 from time import perf_counter
-from typing import List, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
-from ..core.tracing import EV_FRAME_RESUME, EV_FRAME_SUSPEND, EV_TASK_START
+from ..core.tracing import (EV_FRAME_RESUME, EV_FRAME_SUSPEND, EV_PHASE_BEGIN,
+                            EV_PHASE_END, EV_TASK_START)
+from .trace import RuntimeTrace, assemble
 
-__all__ = ["FlightRecorder", "NullRecorder", "NULL_RECORDER",
-           "live_recorders"]
+__all__ = ["FlightRecorder", "NullRecorder", "NULL_RECORDER", "Window",
+           "live_recorders", "recorder_for"]
 
 #: raw record: (t, event kind, label, a, b) — worker id is the ring index
 RawEvent = Tuple[float, str, str, int, int]
+#: a snapshot record: (worker, t, event kind, label, a, b); worker -1 is the
+#: external ring
+Record = Tuple[int, float, str, str, int, int]
+#: a position in every ring (``FlightRecorder.mark``): events emitted so far
+Mark = Tuple[int, ...]
 
 _live: "weakref.WeakSet[FlightRecorder]" = weakref.WeakSet()
 
@@ -60,20 +74,29 @@ class _Ring:
         self.buf[self.n % self.cap] = item
         self.n += 1
 
-    def reset(self) -> None:
-        self.n = 0
+    def window(self, lo: int, hi: int) -> Tuple[List[RawEvent], int]:
+        """The surviving events of emission indices ``[lo, hi)``, in
+        emission order, and how many of that range were overwritten."""
+        cap, buf = self.cap, self.buf
+        first = max(lo, self.n - cap)
+        lost = max(0, min(first, hi) - lo)
+        out = [buf[i % cap] for i in range(first, hi)]
+        return [e for e in out if e is not None], lost
 
-    @property
-    def dropped(self) -> int:
-        return max(0, self.n - self.cap)
 
-    def snapshot(self) -> List[RawEvent]:
-        """Events in emission order (oldest surviving first)."""
-        n, cap, buf = self.n, self.cap, self.buf
-        if n <= cap:
-            return [e for e in buf[:n] if e is not None]
-        head = n % cap
-        return [e for e in buf[head:] + buf[:head] if e is not None]
+class Window(NamedTuple):
+    """The raw events of a stretch of a recorder's life, between two marks
+    (:meth:`FlightRecorder.mark`): ``events`` as ``(worker, t, kind, label,
+    a, b)`` records sorted by time (``t`` on ``perf_counter``), ``dropped``
+    the events of the stretch that ring overflow overwrote."""
+
+    events: List[Record]
+    dropped: int
+    n_workers: int
+
+    def assemble(self) -> RuntimeTrace:
+        """The stretch as a :class:`~repro.obs.trace.RuntimeTrace`."""
+        return assemble(self.events, self.n_workers, dropped=self.dropped)
 
 
 class NullRecorder:
@@ -101,6 +124,12 @@ class NullRecorder:
     def emit_resource(self, worker, kind, task, n_res=0):
         return None
 
+    def phase_begin(self, label):
+        return None
+
+    def phase_end(self, label):
+        return None
+
     def begin_run(self):
         return None
 
@@ -110,15 +139,17 @@ NULL_RECORDER = NullRecorder()
 
 
 class FlightRecorder:
-    """Per-worker event rings for one executor (dispatch strategy).
+    """Per-worker event rings for one session (or one executor).
 
     ``emit(worker, kind, label, a, b)`` timestamps with ``perf_counter``
     and appends to ``worker``'s ring; ``worker=-1`` routes to the shared
-    external ring (non-worker threads).  ``begin_run`` resets the rings so
-    a snapshot only ever covers the current run.
+    external ring (non-worker threads).  ``begin_run`` marks where a run
+    starts: :meth:`run_window` covers the current run, :meth:`window` any
+    stretch between two marks (:meth:`mark`).
     """
 
-    __slots__ = ("n_workers", "rings", "_ext_lock", "__weakref__")
+    __slots__ = ("n_workers", "rings", "_ext_lock", "_run_mark",
+                 "__weakref__")
 
     enabled = True
 
@@ -128,6 +159,7 @@ class FlightRecorder:
         # `rings[worker]` correct for worker ids in [-1, n_workers)
         self.rings = [_Ring(capacity) for _ in range(n_workers + 1)]
         self._ext_lock = threading.Lock()
+        self._run_mark: Mark = (0,) * (n_workers + 1)
         _live.add(self)
 
     def emit(self, worker, kind, label="", a=-1, b=-1):
@@ -161,23 +193,63 @@ class FlightRecorder:
         EV_RESOURCE_* constants; label building stays off the null path)."""
         self.emit(worker, kind, task.name, task.tid, n_res)
 
+    # -- host phases of the caller (``engine.step``, ``session.run``, ...):
+    # begin/end point events on the external ring, tagged with the calling
+    # thread so phases of concurrent callers pair up apart -----------------
+    def phase_begin(self, label):
+        self.emit(-1, EV_PHASE_BEGIN, label, threading.get_ident())
+
+    def phase_end(self, label):
+        self.emit(-1, EV_PHASE_END, label, threading.get_ident())
+
+    # -- marks and windows ------------------------------------------------
+    def mark(self) -> Mark:
+        """The current position in every ring (events emitted so far)."""
+        return tuple(r.n for r in self.rings)
+
     def begin_run(self):
-        for ring in self.rings:
-            ring.reset()
+        self._run_mark = self.mark()
 
-    @property
-    def dropped(self) -> int:
-        return sum(r.dropped for r in self.rings)
+    def window(self, since: Optional[Mark] = None,
+               until: Optional[Mark] = None) -> Window:
+        """Every event emitted between the marks ``since`` (default: the
+        recorder's start) and ``until`` (default: now) that survives in
+        the rings, with the count of those ring overflow overwrote."""
+        rings = self.rings
+        since = since if since is not None else (0,) * len(rings)
+        until = until if until is not None else self.mark()
+        out: List[Record] = []
+        dropped = 0
+        for i, ring in enumerate(rings):
+            w = i if i < self.n_workers else -1
+            events, lost = ring.window(since[i], until[i])
+            dropped += lost
+            out.extend((w, t, kind, label, a, b)
+                       for (t, kind, label, a, b) in events)
+        out.sort(key=lambda e: e[1])
+        return Window(out, dropped, self.n_workers)
 
-    def snapshot(self) -> List[Tuple[int, float, str, str, int, int]]:
+    def run_window(self) -> Window:
+        """The events of the current run (since the last ``begin_run``)."""
+        return self.window(self._run_mark)
+
+    def snapshot(self) -> List[Record]:
         """All events of the current run as ``(worker, t, kind, label, a,
         b)`` tuples, globally sorted by timestamp.  External-ring events
         come back with ``worker = -1``."""
-        out: List[Tuple[int, float, str, str, int, int]] = []
-        for w in range(self.n_workers):
-            for (t, kind, label, a, b) in self.rings[w].snapshot():
-                out.append((w, t, kind, label, a, b))
-        for (t, kind, label, a, b) in self.rings[-1].snapshot():
-            out.append((-1, t, kind, label, a, b))
-        out.sort(key=lambda e: e[1])
-        return out
+        return self.run_window().events
+
+
+def recorder_for(trace, n_workers: int):
+    """The recorder an executor of ``n_workers`` writes to: ``trace`` may
+    be False/None (the no-op singleton), True (a private recorder) or a
+    :class:`FlightRecorder` to share (a session's)."""
+    if isinstance(trace, FlightRecorder):
+        if trace.n_workers != n_workers:
+            raise ValueError(
+                f"a recorder of {trace.n_workers} workers cannot trace an "
+                f"executor of {n_workers}")
+        return trace
+    if trace is None or isinstance(trace, NullRecorder):
+        return NULL_RECORDER
+    return FlightRecorder(n_workers) if trace else NULL_RECORDER

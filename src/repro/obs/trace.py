@@ -16,12 +16,16 @@ span instead of double-counting it, so per-worker busy time never exceeds
 wall clock.  Steals, replay fallbacks and frame suspensions additionally
 land as zero-length ``steal``/``switch`` marker events so ``count()``
 reconciles exactly with ``RunReport.stats``.
+
+The caller's host phases (``engine.step``, ``session.run``, ...) are not
+worker spans: :func:`assemble` leaves them out, and :func:`phase_spans`
+pairs them into spans (:class:`PhaseSpan`) on the recorder's clock.
 """
 
 from __future__ import annotations
 
 from collections import defaultdict
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 from ..core.tracing import (
     EV_BARRIER_DONE,
@@ -35,6 +39,8 @@ from ..core.tracing import (
     EV_GANG_EXIT,
     EV_GANG_RESERVE,
     EV_PARK,
+    EV_PHASE_BEGIN,
+    EV_PHASE_END,
     EV_REPLAY_FALLBACK,
     EV_REPLAY_SKIP,
     EV_REPLAY_STALL,
@@ -58,7 +64,7 @@ from ..core.tracing import (
     Trace,
 )
 
-__all__ = ["RuntimeTrace", "assemble"]
+__all__ = ["PhaseSpan", "RuntimeTrace", "assemble", "phase_spans"]
 
 #: counter name -> point-event kind it mirrors (RunReport.stats parity)
 _COUNTER_EVENTS = {
@@ -92,12 +98,16 @@ class RuntimeTrace(Trace):
     """A live-executor trace in the simulator's ``Event`` schema, plus the
     runtime-only extras: exact point-event ``counters`` (reconciling with
     ``RunReport.stats``), steal / frame-wake flow edges (Perfetto arrows),
-    ring-overflow ``dropped`` count, and multi-run :meth:`metrics`."""
+    ring-overflow ``dropped`` count, and multi-run :meth:`metrics`.
+    ``t_base`` is the ``perf_counter`` reading of the trace's t=0 (None
+    for an empty trace), so its spans map onto other clocks
+    (:mod:`repro.obs.clock`)."""
 
     def __init__(self, n_workers: int):
         super().__init__(n_workers)
         self.counters: Dict[str, int] = {}
         self.dropped = 0
+        self.t_base: Optional[float] = None
         #: (victim worker, thief worker, t, unit label) per successful steal
         self.steal_flows: List[Tuple[int, int, float, str]] = []
         #: (waker worker, t_wake, resume worker, t_resume, label) per
@@ -200,14 +210,13 @@ class RuntimeTrace(Trace):
         return dict(metrics)
 
     @classmethod
-    def from_recorder(cls, recorder, n_workers: Optional[int] = None
-                      ) -> "RuntimeTrace":
-        return assemble(recorder.snapshot(),
-                        n_workers if n_workers is not None
-                        else recorder.n_workers,
-                        dropped=recorder.dropped)
+    def from_recorder(cls, recorder) -> "RuntimeTrace":
+        """The recorder's current run, assembled."""
+        return recorder.run_window().assemble()
 
 
+# host phases: paired by phase_spans, not part of the worker spans
+_PHASES = (EV_PHASE_BEGIN, EV_PHASE_END)
 # boundary events: these open/close the per-worker unit stack
 _OPENERS = {EV_TASK_START, EV_FRAME_RESUME, EV_GANG_ENTER, EV_BLOCK,
             EV_BARRIER_WAIT, EV_PARK}
@@ -250,14 +259,17 @@ def assemble(snapshot: List[Tuple[int, float, str, str, int, int]],
     """Build a :class:`RuntimeTrace` from a recorder snapshot (``(worker,
     t, kind, label, a, b)`` tuples, any order).  Timestamps are shifted so
     the earliest event is ``t=0`` (simulator convention; keeps
-    ``makespan`` meaningful)."""
+    ``makespan`` meaningful); ``t_base`` keeps the shift.  Host phase
+    events are left out (see :func:`phase_spans`)."""
     rt = RuntimeTrace(n_workers)
     rt.dropped = dropped
-    if not snapshot:
+    events = sorted((e for e in snapshot if e[2] not in _PHASES),
+                    key=lambda e: e[1])
+    if not events:
         rt.counters = {k: 0 for k in _COUNTER_EVENTS}
         return rt
-    events = sorted(snapshot, key=lambda e: e[1])
     t_base = events[0][1]
+    rt.t_base = t_base
     t_end = events[-1][1] - t_base
 
     counters: Dict[str, int] = defaultdict(int)
@@ -353,3 +365,33 @@ def assemble(snapshot: List[Tuple[int, float, str, str, int, int]],
     rt.counters = dict(counters)
     rt.steal_victims = victims
     return rt
+
+
+class PhaseSpan(NamedTuple):
+    """One host phase of a caller thread: ``label`` (``engine.step``,
+    ``session.run``, ...), ``t0``/``t1`` on ``perf_counter``."""
+
+    label: str
+    t0: float
+    t1: float
+    thread: int
+
+
+def phase_spans(events: Iterable[Tuple[int, float, str, str, int, int]]
+                ) -> List[PhaseSpan]:
+    """Pair the phase begin/end events of a recorder snapshot or window
+    into spans, sorted by start.  Pairs form per calling thread and label,
+    innermost first; a begin whose end is missing (the phase raised, or
+    the window ends inside it) and an end whose begin fell outside the
+    window make no span."""
+    open_: Dict[Tuple[int, str], List[float]] = defaultdict(list)
+    out: List[PhaseSpan] = []
+    for (_, t, ev, label, thread, _) in events:
+        if ev == EV_PHASE_BEGIN:
+            open_[(thread, label)].append(t)
+        elif ev == EV_PHASE_END:
+            stack = open_.get((thread, label))
+            if stack:
+                out.append(PhaseSpan(label, stack.pop(), t, thread))
+    out.sort(key=lambda p: (p.t0, -p.t1))
+    return out

@@ -44,7 +44,7 @@ class ReplayExecutor:
         stall_timeout: float = 1e-3,
         block_poll: float = 0.05,
         check_digest: bool = True,
-        trace: bool = False,
+        trace: Any = False,
         core: Optional[ExecutorCore] = None,
     ):
         if core is not None and core.n_workers != recording.n_workers:
@@ -56,7 +56,6 @@ class ReplayExecutor:
         self.stall_timeout = stall_timeout
         self.block_poll = block_poll
         self.check_digest = check_digest
-        self.trace_enabled = trace
         #: assembled :class:`~repro.obs.trace.RuntimeTrace` of the most
         #: recent traced replay (None with ``trace=False``)
         self.last_trace = None
@@ -66,6 +65,7 @@ class ReplayExecutor:
         self._owns_core = core is None
         self._dispatch = ReplayDispatch(recording, stall_timeout=stall_timeout,
                                         trace=trace)
+        self.trace_enabled = self._dispatch.trace_enabled
 
     # ------------------------------------------------------------------
     # lifecycle

@@ -221,9 +221,11 @@ class ReplayPool:
         Forwarded to each :class:`ReplayExecutor`.
     trace:
         Run every serve (replay *and* the dynamic warmup/record paths) with
-        the flight recorder on.  Each :class:`PoolRun` then carries the
-        run's :class:`~repro.obs.trace.RuntimeTrace` and the entry keeps
-        rolling per-shape trace metrics (``PoolEntryStats.trace_metrics``).
+        the flight recorder on: True gives each executor a recorder of its
+        own, a :class:`~repro.obs.FlightRecorder` (a session's) is shared
+        by all of them.  Each :class:`PoolRun` then carries the run's
+        :class:`~repro.obs.trace.RuntimeTrace` and the entry keeps rolling
+        per-shape trace metrics (``PoolEntryStats.trace_metrics``).
     shared_cores:
         Lease worker cores from the process-global
         :class:`~repro.exec.registry.CoreRegistry` (default): several pools
@@ -245,7 +247,7 @@ class ReplayPool:
         compile_after: Optional[int] = None,
         max_shapes: Optional[int] = None,
         stall_timeout: float = 1e-3,
-        trace: bool = False,
+        trace: Any = False,
         shared_cores: bool = True,
     ):
         if max_shapes is not None and max_shapes < 1:
@@ -628,7 +630,10 @@ class ReplayPool:
         from ..core.runtime import Runtime
 
         core = None if transient else self._core_for(n_workers)
-        rt = Runtime(n_workers, core=core, trace=self.trace, **rt_kwargs)
+        # transient threads run beside the serving core: they must not
+        # write to a recorder shared with it (one writer per ring)
+        trace = bool(self.trace) if transient else self.trace
+        rt = Runtime(n_workers, core=core, trace=trace, **rt_kwargs)
         with rt:
             t0 = time.perf_counter()
             results = rt.run(graph, timeout=timeout, record=record)

@@ -37,6 +37,12 @@ The engine clock is wall time by default; passing ``step_time`` switches
 to a deterministic virtual clock (each decode step advances time by that
 amount) so tests can assert batch compositions and latency numbers
 exactly.
+
+With a traced session the engine writes its host phases into the
+session's flight recorder: ``engine.step`` encloses ``engine.admit`` (one
+``engine.prefill`` per admitted request, until its first token is on the
+host), ``engine.run_graph`` (the ``Session.run`` of the step graph) and
+``engine.collect`` (the lane-token reads and bookkeeping after it).
 """
 
 from __future__ import annotations
@@ -181,8 +187,6 @@ class ContinuousBatchingEngine:
         self._warm_steps = 0
         self._lane_steps = 0
         self._shape_counts: Dict[int, int] = {}
-        self._trace: Optional[Any] = None
-        self._trace_k = 0
         self._vnow = 0.0
         self._t0 = time.perf_counter()
 
@@ -306,6 +310,7 @@ class ContinuousBatchingEngine:
         whose budget is 1 token (or whose first token is EOS) complete
         here without ever occupying a decode slot."""
         admitted = False
+        phases = self.session.recorder
         while len(self._active) < self.max_batch:
             ok, req = self._admission.try_recv()
             if not ok:
@@ -313,9 +318,11 @@ class ContinuousBatchingEngine:
             admitted = True
             rec = self._records[req.rid]
             rec.admitted_s = now
+            phases.phase_begin("engine.prefill")
             cache, logits = self._prefill_fn(req.prompt)
             st = RequestState(req, cache, self._sample_fn(logits))
             tid = st.note_token(st.tok)
+            phases.phase_end("engine.prefill")
             t_first = self._now()
             rec.first_token_s = t_first
             rec.tokens.append(tid)
@@ -330,12 +337,20 @@ class ContinuousBatchingEngine:
     def step(self) -> bool:
         """Admit arrivals into free lanes, then run one decode step over
         the in-flight set.  Returns False when there was nothing to do."""
+        phases = self.session.recorder
+        phases.phase_begin("engine.step")
+        phases.phase_begin("engine.admit")
         admitted = self._admit(self._now())
+        phases.phase_end("engine.admit")
         if not self._active:
+            phases.phase_end("engine.step")
             return admitted
         k = len(self._active)
         graph, key = self._graph_for(k)
+        phases.phase_begin("engine.run_graph")
         report = self.session.run(graph, key=key)
+        phases.phase_end("engine.run_graph")
+        phases.phase_begin("engine.collect")
         if self.step_time is not None:
             self._vnow += self.step_time
         now = self._now()
@@ -344,10 +359,6 @@ class ContinuousBatchingEngine:
         self._shape_counts[k] = self._shape_counts.get(k, 0) + 1
         if report.stats.get("pool_mode") in _WARM_MODES:
             self._warm_steps += 1
-        if report.trace is not None and k >= self._trace_k:
-            # keep the most heavily loaded step's trace: the steady-state
-            # window the bench exports
-            self._trace, self._trace_k = report.trace, k
         still: List[RequestState] = []
         for i, st in enumerate(self._active):
             tid = st.note_token(self._step_tokens[i])
@@ -360,6 +371,8 @@ class ContinuousBatchingEngine:
             else:
                 still.append(st)
         self._active = still
+        phases.phase_end("engine.collect")
+        phases.phase_end("engine.step")
         return True
 
     # ------------------------------------------------------------------
@@ -564,13 +577,16 @@ class ContinuousBatchingEngine:
 
     def report(self) -> ServingReport:
         """Snapshot of everything served so far (complete requests only
-        appear with their final token streams)."""
+        appear with their final token streams).  With a traced session,
+        ``trace`` is the assembled trace of the session recorder's window:
+        every surviving event of every step."""
         if self._done != len(self._records):
             stranded = [rid for rid, rec in self._records.items()
                         if not rec.done_s]
             raise RuntimeError(
                 f"{len(stranded)} request(s) still in flight: "
                 f"{stranded[:8]}")
+        window = self.session.trace_window()
         return ServingReport(
             records=dict(self._records),
             steps=self._steps,
@@ -579,5 +595,5 @@ class ContinuousBatchingEngine:
             max_batch=self.max_batch,
             wall_s=time.perf_counter() - self._t0,
             shape_counts=dict(self._shape_counts),
-            trace=self._trace,
+            trace=window.assemble() if window is not None else None,
         )

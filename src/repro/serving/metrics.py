@@ -66,8 +66,9 @@ class ServingReport:
     counts decode-step graphs executed, ``warm_steps`` how many of them the
     pool served as warm replays (0 under a dynamic session),
     ``lane_steps`` the total lanes occupied across steps (occupancy =
-    ``lane_steps / steps / max_batch``).  ``trace`` is the flight-recorder
-    trace of the most heavily loaded step when the session traced.
+    ``lane_steps / steps / max_batch``).  ``trace`` is the assembled
+    flight-recorder trace of the session recorder's window (every step's
+    surviving events) when the session traced.
     """
 
     records: Dict[int, RequestRecord]

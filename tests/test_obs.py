@@ -17,6 +17,9 @@ import pytest
 import repro
 from repro.core.policies import POLICIES, VictimPolicy, register_policy
 from repro.core.tracing import (
+    EV_PHASE_BEGIN,
+    EV_PHASE_END,
+    EV_TASK_END,
     EV_TASK_START,
     KIND_BARRIER,
     KIND_COMPUTE,
@@ -26,9 +29,14 @@ from repro.core.tracing import (
 )
 from repro.obs import (
     NULL_RECORDER,
+    ClockMap,
     FlightRecorder,
     RuntimeTrace,
+    anchor_spans,
+    assemble,
     load_trace,
+    phase_spans,
+    profile_anchor,
     validate_trace_json,
     write_trace,
 )
@@ -105,6 +113,8 @@ def test_null_recorder_emits_allocate_nothing():
             r.emit_task_start(0, task)
             r.emit_frame_resume(1, frame)
             r.emit_frame_suspend(1, frame, req)
+            r.phase_begin("engine.step")
+            r.phase_end("engine.step")
             r.begin_run()
 
     burst(100)                      # warm free lists / specializations
@@ -118,7 +128,7 @@ def test_null_recorder_emits_allocate_nothing():
     finally:
         gc.enable()
     # interpreter background noise can add a block or two once; a per-call
-    # cost would show in EVERY sample across 12k calls
+    # cost would show in EVERY sample across 16k calls
     assert min(deltas) == 0, f"no-op emit path allocates: deltas={deltas}"
 
 
@@ -139,10 +149,13 @@ def test_ring_wraps_and_counts_dropped():
     ring = _Ring(4)
     for i in range(7):
         ring.append((float(i), "k", "", i, 0))
-    assert ring.dropped == 3
-    assert [e[3] for e in ring.snapshot()] == [3, 4, 5, 6]
-    ring.reset()
-    assert ring.snapshot() == [] and ring.dropped == 0
+    events, lost = ring.window(0, 7)
+    assert [e[3] for e in events] == [3, 4, 5, 6] and lost == 3
+    # a window of emission indices counts what was overwritten in it
+    events, lost = ring.window(1, 5)
+    assert [e[3] for e in events] == [3, 4] and lost == 2
+    assert ring.window(5, 7) == (ring.window(0, 7)[0][2:], 0)
+    assert ring.window(7, 7) == ([], 0)
 
 
 def test_recorder_routes_external_threads_to_extra_ring():
@@ -151,6 +164,62 @@ def test_recorder_routes_external_threads_to_extra_ring():
     rec.emit(-1, "b", "", 2)       # non-worker thread (e.g. outside waker)
     snap = rec.snapshot()
     assert [(w, k) for (w, _, k, _, _, _) in snap] == [(0, "a"), (-1, "b")]
+
+
+def test_windows_between_marks_count_what_the_rings_overwrote():
+    rec = FlightRecorder(1, capacity=4)
+    for i in range(3):
+        rec.emit(0, "a", "", i)
+    m = rec.mark()
+    for i in range(3, 10):
+        rec.emit(0, "a", "", i)
+    # 10 events on a ring of 4: indices 0..5 are gone, 3..5 of them after m
+    w = rec.window(since=m)
+    assert [e[4] for e in w.events] == [6, 7, 8, 9] and w.dropped == 3
+    assert rec.window(until=m) == ([], 3, 1)
+    whole = rec.window()
+    assert whole.dropped == 6 and whole.events == w.events
+
+
+def test_run_trace_is_the_run_alone_on_a_recorder_that_keeps_earlier_runs():
+    """``begin_run`` marks instead of resetting: a run's trace is what a
+    recorder reset at the run's start gave on the same events, whatever
+    came before it and whatever host phases the caller interleaves."""
+    def run_events(t0, tid0):
+        return [(0, t0, EV_TASK_START, "compute|a", tid0, 0),
+                (0, t0 + 1.0, EV_TASK_END, "", tid0, -1),
+                (1, t0 + 0.5, EV_TASK_START, "comm|b", tid0 + 1, 0),
+                (1, t0 + 2.0, EV_TASK_END, "", tid0 + 1, -1)]
+
+    shared, fresh = FlightRecorder(2), FlightRecorder(2)
+    for (w, t, kind, label, a, b) in run_events(10.0, 0):
+        shared.rings[w].append((t, kind, label, a, b))
+    shared.begin_run()
+    second = run_events(20.0, 0)
+    phases = [(-1, 19.5, EV_PHASE_BEGIN, "session.execute", 1, -1),
+              (-1, 23.0, EV_PHASE_END, "session.execute", 1, -1)]
+    for (w, t, kind, label, a, b) in sorted(second + phases, key=lambda e: e[1]):
+        shared.rings[w].append((t, kind, label, a, b))
+    for (w, t, kind, label, a, b) in second:
+        fresh.rings[w].append((t, kind, label, a, b))
+    got = RuntimeTrace.from_recorder(shared)
+    assert got == RuntimeTrace.from_recorder(fresh) == assemble(second, 2)
+    assert got.t_base == 20.0 and got.counters["tasks"] == 2
+    # the whole window keeps both runs, and the caller's phase pairs up
+    assert shared.window().assemble().counters["tasks"] == 4
+    (span,) = phase_spans(shared.window().events)
+    assert (span.label, span.t0, span.t1) == ("session.execute", 19.5, 23.0)
+
+
+def test_phases_pair_per_thread_and_drop_unmatched_ends():
+    events = [(-1, 1.0, EV_PHASE_BEGIN, "p", 1, -1),
+              (-1, 2.0, EV_PHASE_BEGIN, "p", 2, -1),   # another thread
+              (-1, 3.0, EV_PHASE_END, "p", 1, -1),
+              (-1, 3.5, EV_PHASE_END, "q", 1, -1),     # its begin fell outside
+              (-1, 4.0, EV_PHASE_BEGIN, "p", 1, -1),   # never ends
+              (-1, 5.0, EV_PHASE_END, "p", 2, -1)]
+    assert [(p.t0, p.t1, p.thread) for p in phase_spans(events)] == [
+        (1.0, 3.0, 1), (2.0, 5.0, 2)]
 
 
 # ---------------------------------------------------------------------------
@@ -163,6 +232,34 @@ def test_untraced_session_report_has_no_trace():
         report = s.run(g)
     assert report.trace is None
     assert join in report
+
+
+@pytest.mark.parametrize("scheduler", ["dynamic", "replay", "pool"])
+def test_session_window_keeps_every_run(scheduler):
+    """One recorder per session: every executor it builds writes to it,
+    each run's report traces that run alone, and the window holds all of
+    them with the session's own phases."""
+    n = 4
+    with repro.Session(2, scheduler=scheduler, trace=True) as s:
+        start = s.trace_mark()
+        reports = []
+        for _ in range(n):
+            g, _, _ = _mixed_graph(fanout=3)
+            reports.append(s.run(g))
+        window = s.trace_window(since=start)
+        assert s.recorder.enabled and window.dropped == 0
+    assert s.recorder is NULL_RECORDER and s.trace_window() is None
+    n_tasks = len(g.tasks)
+    for report in reports:
+        assert report.trace.counters["tasks"] == n_tasks
+        assert report.trace.reconcile(report.stats) == {}
+    assert window.assemble().counters["tasks"] == n * n_tasks
+    labels = [p.label for p in phase_spans(window.events)]
+    for phase in ("session.run", "session.plan", "session.execute"):
+        assert labels.count(phase) == n
+    runs = [p for p in phase_spans(window.events) if p.label == "session.run"]
+    for run, report in zip(runs, reports):
+        assert run.t0 <= report.trace.t_base <= run.t1
 
 
 def test_traced_dynamic_run_reconciles_with_stats(tmp_path):
@@ -213,6 +310,41 @@ def test_trace_breakdown_shares_simulator_vocabulary():
     assert run_trace.breakdown().get(KIND_COMPUTE, 0.0) > 0.0
 
 
+def test_recorder_time_maps_inside_the_profile_annotation_around_it(tmp_path):
+    """Anchors put recorder time on the JAX profile's clock: a phase
+    emitted inside a ``TraceAnnotation`` maps inside it, within 50 us."""
+    import time
+
+    import jax
+
+    rec = FlightRecorder(1)
+    jax.profiler.start_trace(str(tmp_path))
+    readings = [profile_anchor()]
+    for _ in range(4):
+        with jax.profiler.TraceAnnotation("obs.probe"):
+            rec.phase_begin("probe")
+            time.sleep(2e-3)
+            rec.phase_end("probe")
+        time.sleep(1e-3)
+    readings.append(profile_anchor())
+    jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    profile = ProfileData.from_file(str(path))
+    clock = ClockMap.from_anchors(readings, anchor_spans(profile))
+    assert clock.error_ns < 50e3
+    assert abs(clock.ns_per_s / 1e9 - 1.0) < 1e-3
+    probes = sorted((e.start_ns, e.start_ns + e.duration_ns)
+                    for plane in profile.planes for line in plane.lines
+                    for e in line.events if e.name == "obs.probe")
+    spans = phase_spans(rec.window().events)
+    assert len(spans) == len(probes) == 4
+    for span, (a, b) in zip(spans, probes):
+        assert clock.ns(span.t0) >= a - 50e3
+        assert clock.ns(span.t1) <= b + 50e3
+
+
 # ---------------------------------------------------------------------------
 # Perfetto export
 # ---------------------------------------------------------------------------
@@ -230,6 +362,7 @@ def test_perfetto_roundtrip_is_exact(tmp_path):
     loaded = load_trace(path)
     assert loaded == trace
     assert loaded.metrics() == trace.metrics()
+    assert loaded.t_base == trace.t_base is not None
 
 
 def test_perfetto_json_shape_and_validation(tmp_path):

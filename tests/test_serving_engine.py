@@ -300,3 +300,31 @@ def test_report_refuses_requests_still_in_flight():
         while eng.in_flight() or eng.queue_depth():
             eng.step()
         assert eng.report().completed == 1
+
+
+# ---------------------------------------------------------------------------
+# the engine's host phases in the session's flight recorder
+def test_traced_engine_records_its_phases_and_reports_the_window():
+    reqs = _requests([3, 5, 2, 4])
+    with repro.Session(2, scheduler="pool", trace=True) as s:
+        report = _engine(s, max_batch=2).run(reqs)
+        spans = repro.obs.phase_spans(s.trace_window().events)
+    by = {}
+    for p in spans:
+        by.setdefault(p.label, []).append(p)
+    assert len(by["engine.prefill"]) == len(reqs)
+    assert len(by["engine.run_graph"]) == len(by["engine.collect"]) == report.steps
+    assert len(by["session.run"]) == report.steps
+    assert len(by["engine.step"]) >= report.steps
+    # each graph run sits inside a step, after its admission, and encloses
+    # the session's run; collection follows it
+    for run, sess, collect in zip(by["engine.run_graph"], by["session.run"],
+                                  by["engine.collect"]):
+        step = next(p for p in by["engine.step"] if p.t0 <= run.t0 <= p.t1)
+        assert step.t0 <= run.t0 and collect.t1 <= step.t1
+        assert run.t0 <= sess.t0 and sess.t1 <= run.t1 <= collect.t0
+    # the report's trace is the whole window: every task of every step
+    lanes = sum(k * n for k, n in report.shape_counts.items())
+    assert report.trace.counters["tasks"] == 2 * lanes + report.steps
+    with repro.Session(2, scheduler="pool") as s:
+        assert _engine(s, max_batch=2).run(_requests([3, 5])).trace is None
