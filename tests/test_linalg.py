@@ -12,6 +12,7 @@ import pytest
 
 jax.config.update("jax_enable_x64", True)
 
+import repro
 from repro.core import run_graph
 from repro.linalg import (
     build_cholesky_graph,
@@ -23,6 +24,7 @@ from repro.linalg import (
     qr_reconstruct,
     random_diagdom,
     random_spd,
+    TileStore,
     to_tiles,
 )
 from repro.linalg.panels import lu_panel_region, qr_form_t, qr_panel_region
@@ -31,6 +33,82 @@ from repro.linalg.panels import lu_panel_region, qr_form_t, qr_panel_region
 class _SerialRegion:
     def barrier(self):
         pass
+
+
+def _tiles_by_slice(a, b):
+    """The store as one slice per tile: what ``to_tiles`` must equal."""
+    nb = a.shape[0] // b
+    return TileStore({(i, j): jnp.asarray(a[i * b:(i + 1) * b, j * b:(j + 1) * b])
+                      for i in range(nb) for j in range(nb)}, nb, b)
+
+
+# ---------------------------------------------------------------------------
+# the tile store
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("shape,b,dtype,host", [
+    ((4, 4), 4, np.float32, False),
+    ((15, 15), 5, np.float32, False),
+    ((12, 12), 4, np.float64, False),
+    ((80, 80), 2, np.float32, False),
+    ((120, 120), 3, np.float64, False),
+    ((12, 12), 4, np.float64, True),
+    ((12, 12), 4, np.float32, True),
+    ((12, 8), 4, np.float32, False),
+    ((12, 12), 5, np.float32, False),
+    ((12, 12), 5, np.float64, True),
+])
+def test_to_tiles_matches_per_tile_slices(shape, b, dtype, host):
+    a = np.random.default_rng(sum(shape) + b).standard_normal(shape).astype(dtype)
+    if not host:
+        a = jnp.asarray(a)
+    if shape[0] != shape[1] or shape[0] % b:
+        with pytest.raises(ValueError, match="square matrix"):
+            to_tiles(a, b)
+        return
+    store = to_tiles(a, b)
+    ref = _tiles_by_slice(a, b)
+    assert (store.nb, store.b) == (shape[0] // b, b)
+    assert store.tiles.keys() == ref.tiles.keys()
+    for k, t in store.tiles.items():
+        assert isinstance(t, jax.Array)
+        assert t.dtype == ref[k].dtype == dtype and t.shape == (b, b)
+        assert np.asarray(t).tobytes() == np.asarray(ref[k]).tobytes(), k
+    # one device buffer per tile
+    ptrs = {t.unsafe_buffer_pointer() for t in store.tiles.values()}
+    assert len(ptrs) == store.nb ** 2
+
+
+def _factor(kind, store):
+    if kind == "cholesky":
+        g = build_cholesky_graph(store.nb, store.b, store=store)
+    elif kind == "lu":
+        g = build_lu_graph(store.nb, store.b, store=store, panel_threads=2)
+    else:
+        g = build_qr_graph(store.nb, store.b, store=store, panel_threads=2)
+    with repro.Session(3) as s:
+        s.run(g, timeout=120.0)
+    if kind == "cholesky":
+        return [cholesky_extract(store)]
+    if kind == "lu":
+        return list(lu_extract(store))
+    return [qr_extract_r(store), qr_reconstruct(store)]
+
+
+@pytest.mark.parametrize("kind", ["cholesky", "lu", "qr"])
+def test_factors_from_to_tiles_match_per_tile_store(kind):
+    """A factorization of ``to_tiles``'s store equals, bit for bit, the
+    same factorization of a store built one slice per tile."""
+    n, b = 96, 24
+    if kind == "cholesky":
+        a = random_spd(n, seed=7)
+    elif kind == "lu":
+        a = random_diagdom(n, seed=7)
+    else:
+        a = jnp.asarray(np.random.default_rng(7).standard_normal((n, n)))
+    got = _factor(kind, to_tiles(a, b))
+    want = _factor(kind, _tiles_by_slice(a, b))
+    for x, y in zip(got, want):
+        assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
 
 
 # ---------------------------------------------------------------------------
