@@ -14,6 +14,7 @@ _MODULES = {
     "gemma3-12b": "gemma3_12b",
     "qwen3-14b": "qwen3_14b",
     "qwen3-moe-235b-a22b": "qwen3_moe_235b_a22b",
+    "qwen3-next-80b-a3b": "qwen3_next_80b_a3b",
     "llama4-maverick-400b-a17b": "llama4_maverick_400b_a17b",
     "zamba2-7b": "zamba2_7b",
     "mamba2-2.7b": "mamba2_2p7b",

@@ -94,11 +94,17 @@ def stack_spec(spec: Spec, n: int) -> Spec:
 # ---------------------------------------------------------------------------
 # norms / rope
 # ---------------------------------------------------------------------------
-def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6,
+            zero_centred: bool = False) -> jnp.ndarray:
+    """RMSNorm in float32; ``zero_centred`` scales by ``1 + scale``
+    (Qwen3-Next), so a scale of zero is the identity."""
     dt = x.dtype
     x = x.astype(jnp.float32)
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return (x * scale.astype(jnp.float32)).astype(dt)
+    scale = scale.astype(jnp.float32)
+    if zero_centred:
+        scale = 1.0 + scale
+    return (x * scale).astype(dt)
 
 
 def rope(x: jnp.ndarray, positions: jnp.ndarray, theta) -> jnp.ndarray:
@@ -122,8 +128,9 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, theta) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 def attn_spec(cfg) -> Spec:
     hd, d = cfg.head_dim, cfg.d_model
+    q_width = cfg.n_heads * hd * (2 if cfg.attn_output_gate else 1)
     s: Spec = {
-        "wq": ((d, cfg.n_heads * hd), ("embed", "heads")),
+        "wq": ((d, q_width), ("embed", "heads")),
         "wk": ((d, cfg.n_kv_heads * hd), ("embed", "kv_heads")),
         "wv": ((d, cfg.n_kv_heads * hd), ("embed", "kv_heads")),
         "wo": ((cfg.n_heads * hd, d), ("heads", "embed")),
@@ -232,34 +239,51 @@ def attention(p: Params, cfg, x: jnp.ndarray, *,
     if theta is None:
         theta = cfg.rope_theta
     kv_src = memory if memory is not None else x
-    q = (x @ p["wq"]).reshape(B, S, cfg.n_heads, hd)
+    gate = None
+    if cfg.attn_output_gate:
+        # per head [q | g]: the second half gates the head's output
+        qg = (x @ p["wq"]).reshape(B, S, cfg.n_heads, 2 * hd)
+        q, gate = qg[..., :hd], qg[..., hd:]
+    else:
+        q = (x @ p["wq"]).reshape(B, S, cfg.n_heads, hd)
     k = (kv_src @ p["wk"]).reshape(B, kv_src.shape[1], cfg.n_kv_heads, hd)
     v = (kv_src @ p["wv"]).reshape(B, kv_src.shape[1], cfg.n_kv_heads, hd)
     if cfg.qk_norm:
-        q = rmsnorm(q, p["gamma_q"], cfg.norm_eps)
-        k = rmsnorm(k, p["gamma_k"], cfg.norm_eps)
+        zc = cfg.norm_zero_centred
+        q = rmsnorm(q, p["gamma_q"], cfg.norm_eps, zc)
+        k = rmsnorm(k, p["gamma_k"], cfg.norm_eps, zc)
+
+    def out_proj(o):
+        if gate is not None:
+            o = o * jax.nn.sigmoid(gate)
+        return o.reshape(B, S, cfg.n_heads * hd) @ p["wo"]
+
+    rotate = rope
+    if cfg.rotary_dim < hd:
+        rot = cfg.rotary_dim
+
+        def rotate(t, pos, th):
+            return jnp.concatenate([rope(t[..., :rot], pos, th), t[..., rot:]], axis=-1)
 
     if memory is not None:
         # cross attention: full, non-causal, no rope
         out = _chunked_attn(q, k, v, causal=False, window=0, q_offset=0)
-        out = out.reshape(B, S, cfg.n_heads * hd) @ p["wo"]
-        return out, None
+        return out_proj(out), None
 
     if cache is None:
         if positions is None:
             positions = jnp.arange(S)[None, :]
         if use_rope:
-            q = rope(q, positions, theta)
-            k = rope(k, positions, theta)
+            q = rotate(q, positions, theta)
+            k = rotate(k, positions, theta)
         out = _chunked_attn(q, k, v, causal=causal, window=window, q_offset=0)
-        out = out.reshape(B, S, cfg.n_heads * hd) @ p["wo"]
-        return out, {"k": k, "v": v}
+        return out_proj(out), {"k": k, "v": v}
 
     # -- decode step ------------------------------------------------------
     idx = cache_index  # scalar int32: current cache fill
     if use_rope:
-        q = rope(q, jnp.full((B, S), idx, jnp.int32), theta)
-        k = rope(k, jnp.full((B, S), idx, jnp.int32), theta)
+        q = rotate(q, jnp.full((B, S), idx, jnp.int32), theta)
+        k = rotate(k, jnp.full((B, S), idx, jnp.int32), theta)
     z = jnp.zeros((), jnp.int32)
     idx32 = jnp.asarray(idx, jnp.int32)
     ck = lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
@@ -267,8 +291,7 @@ def attention(p: Params, cfg, x: jnp.ndarray, *,
     cv = lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
                                   (z, idx32, z, z))
     out = decode_attention(q, ck, cv, idx + S, window=window)
-    out = out.reshape(B, S, cfg.n_heads * hd) @ p["wo"]
-    return out, {"k": ck, "v": cv}
+    return out_proj(out), {"k": ck, "v": cv}
 
 
 def decode_attention(q, ck, cv, length, *, window: int = 0):
@@ -317,15 +340,20 @@ def mlp(p: Params, x: jnp.ndarray) -> jnp.ndarray:
 # MoE (dropless-ish: per-expert static capacity, EP over 'model')
 # ---------------------------------------------------------------------------
 def moe_spec(cfg) -> Spec:
+    """The router spans all ``n_experts``; the expert stacks hold
+    ``cfg.n_held_experts`` of them."""
     d, e, fe = cfg.d_model, cfg.n_experts, cfg.d_expert
+    held = cfg.n_held_experts
     s: Spec = {
         "router": ((d, e), ("embed", None)),
-        "wg": ((e, d, fe), ("experts", "embed", None)),
-        "wu": ((e, d, fe), ("experts", "embed", None)),
-        "wd": ((e, fe, d), ("experts", None, "embed")),
+        "wg": ((held, d, fe), ("experts", "embed", None)),
+        "wu": ((held, d, fe), ("experts", "embed", None)),
+        "wd": ((held, fe, d), ("experts", None, "embed")),
     }
     if cfg.shared_expert:
         s["shared"] = mlp_spec(cfg, cfg.d_ff or cfg.d_expert)
+        if cfg.shared_expert_gate:
+            s["shared_gate"] = ((d, 1), ("embed", None))
     return s
 
 
@@ -470,6 +498,106 @@ def moe(p: Params, cfg, x: jnp.ndarray, *, shard_ctx=None) -> jnp.ndarray:
     if cfg.shared_expert and "shared" in p:
         y = y + mlp(p["shared"], x)
     return y
+
+
+#: routing counters of one dropless expert layer, in this order
+ROUTING_COUNTERS = ("held_assignments", "max_held_load", "held_touched")
+
+
+def moe_dropless(p: Params, cfg, x: jnp.ndarray, layer
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Top-k expert layer that holds experts ``[cfg.expert_offset,
+    + cfg.n_held_experts)`` of the ``cfg.n_experts`` it routes over, with
+    no capacity: every token-expert pair that lands on a held expert is
+    computed, and pairs routed to experts held elsewhere do no work here
+    (their part is left out, as on a chip of an expert-parallel group).
+
+    Routing is softmax in float32 over all experts, top-k, renormalised.
+    Many pairs (prefill) are sorted by expert and run as grouped products
+    (``lax.ragged_dot``); a few (decode) loop over the held experts they
+    routed to, reading only those experts' weights.  The shared expert,
+    behind its sigmoid gate where the configuration has one, is added
+    whole.  Returns ``(y, counters)``, ``counters`` an int32 vector in
+    :data:`ROUTING_COUNTERS` order: pairs on held experts, the largest
+    held expert's load, held experts touched.
+
+    The expert stacks ``wg``/``wu``/``wd`` hold every layer's experts
+    (layers, held, ...) and ``layer`` picks one: a decode step inside a
+    layer scan then reads single experts from the whole stack, where a
+    sliced layer would be copied whole into the loop."""
+    B, S, D = x.shape
+    T, k = B * S, cfg.top_k
+    n = cfg.n_held_experts
+    xf = x.reshape(T, D)
+    with jax.named_scope("moe.route"):
+        # logits leave the product in float32: bf16 logits would flip
+        # near-tied choices of the top k
+        logits = jnp.matmul(xf, p["router"], preferred_element_type=jnp.float32)
+        wts, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        wts = wts / jnp.maximum(wts.sum(-1, keepdims=True), 1e-9)
+        local = ids.reshape(-1) - cfg.expert_offset               # (T*k,)
+        held = (local >= 0) & (local < n)
+        group = jnp.where(held, local, n).astype(jnp.int32)       # n: held elsewhere
+        load = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
+        counters = jnp.stack([jnp.sum(held, dtype=jnp.int32), jnp.max(load),
+                              jnp.sum(load > 0, dtype=jnp.int32)])
+    with jax.named_scope("moe.experts"):
+        pair_w = jnp.where(held, wts.reshape(-1), 0.0)
+        if T * k < n:
+            y = _held_pairs_loop(xf, group, pair_w, held, p, k, layer)
+        else:
+            stacks = {name: lax.dynamic_index_in_dim(p[name], layer, 0, keepdims=False)
+                      for name in ("wg", "wu", "wd")}
+            y = _held_pairs_grouped(xf, group, pair_w, load, stacks, k)
+    y = y.astype(x.dtype).reshape(B, S, D)
+    if cfg.shared_expert:
+        shared = mlp(p["shared"], x)
+        if cfg.shared_expert_gate:
+            shared = jax.nn.sigmoid(x @ p["shared_gate"]) * shared
+        y = y + shared
+    return y, counters
+
+
+def _expert(xe, wg, wu, wd):
+    h = jax.nn.silu(xe @ wg) * (xe @ wu)
+    return jnp.matmul(h, wd, preferred_element_type=jnp.float32)
+
+
+def _held_pairs_grouped(xf, group, pair_w, load, p, k):
+    """Every held pair at once: pairs sorted by expert (those held
+    elsewhere last, outside every group), grouped products over the held
+    experts' stacks, weighted rows scattered back to their tokens."""
+    T, D = xf.shape
+    order = jnp.argsort(group, stable=True)
+    tok = order // k
+    xs = xf[tok]
+    h = jax.nn.silu(lax.ragged_dot(xs, p["wg"], load)) * lax.ragged_dot(xs, p["wu"], load)
+    ys = lax.ragged_dot(h, p["wd"], load, preferred_element_type=jnp.float32)
+    ys = ys * pair_w[order][:, None]
+    return jnp.zeros((T, D), jnp.float32).at[tok].add(ys)
+
+
+def _held_pairs_loop(xf, group, pair_w, held, p, k, layer):
+    """A few pairs: one loop trip per held pair, each reading its own
+    expert's weights (pairs held elsewhere sort last and never run)."""
+    T, D = xf.shape
+    order = jnp.argsort(jnp.where(held, 0, 1), stable=True)
+
+    def one(name, e):
+        w = p[name]
+        z = jnp.zeros((), jnp.int32)
+        start = (jnp.asarray(layer, jnp.int32), e, z, z)
+        return lax.dynamic_slice(w, start, (1, 1) + w.shape[2:])[0, 0]
+
+    def body(i, y):
+        j = order[i]
+        e, t = group[j], j // k
+        w = [one(name, e) for name in ("wg", "wu", "wd")]
+        ye = _expert(lax.dynamic_index_in_dim(xf, t, 0), *w)
+        return y.at[t].add(ye[0] * pair_w[j])
+
+    return lax.fori_loop(0, jnp.sum(held, dtype=jnp.int32), body,
+                         jnp.zeros((T, D), jnp.float32))
 
 
 def _moe_capacity(t_local: int, cfg) -> int:

@@ -6,6 +6,13 @@ of depth).  Heterogeneous stacks (gemma3 local:global, zamba2 shared
 attention, llama-vision cross-attention) use per-layer flag arrays as scan
 xs — one compiled body, no per-layer HLO.
 
+The Gated DeltaNet hybrid (``gdn``, Qwen3-Next) has two kinds of layer,
+each with its own weights: ``full_attn_every - 1`` Gated DeltaNet layers
+(``models/gdn.py``) then one gated full-attention layer, repeating, with a
+dropless expert layer in every layer.  It scans over periods, with the
+DeltaNet layers of a period unrolled; its cache holds K/V for the full
+layers and convolution and recurrent state for the others.
+
 Entry points (all pure; jit/shard them from repro.launch):
 
 * ``model_spec(cfg)`` / ``init_params(cfg, key)`` / ``abstract_params(cfg)``
@@ -26,6 +33,7 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
+from . import gdn as G
 from . import layers as L
 from . import ssm as S
 from .config import ModelConfig
@@ -63,13 +71,28 @@ def block_spec(cfg: ModelConfig) -> Dict:
     return s
 
 
+def _gdn_block_specs(cfg: ModelConfig):
+    """(Gated DeltaNet layer, full-attention layer) of the gdn family."""
+    d = cfg.d_model
+    common = {"ln1": ((d,), ("embed",)), "ln2": ((d,), ("embed",)),
+              "moe": L.moe_spec(cfg)}
+    return dict(common, gdn=G.gdn_spec(cfg)), dict(common, attn=L.attn_spec(cfg))
+
+
 def model_spec(cfg: ModelConfig) -> Dict:
     v = padded_vocab(cfg)
     d = cfg.d_model
+    if cfg.family == "gdn":
+        lin, full = _gdn_block_specs(cfg)
+        n_full = cfg.n_full_layers
+        blocks = {"lin": L.stack_spec(lin, cfg.n_layers - n_full),
+                  "full": L.stack_spec(full, n_full)}
+    else:
+        blocks = L.stack_spec(block_spec(cfg), cfg.n_layers)
     spec: Dict = {
         "embed": {"table": ((v, d), ("vocab", "embed"))},
         "final_norm": ((d,), ("embed",)),
-        "blocks": L.stack_spec(block_spec(cfg), cfg.n_layers),
+        "blocks": blocks,
     }
     if not cfg.tie_embeddings:
         spec["unembed"] = {"out": ((d, v), ("embed", "vocab"))}
@@ -321,6 +344,9 @@ def forward(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], ctx=None,
     B, Sq = tokens.shape
     x = embed_lookup(params["embed"]["table"], tokens, ctx)
     positions = jnp.arange(Sq)[None, :]
+    if cfg.family == "gdn":
+        x, _ = _gdn_stack(params, cfg, x, positions, remat=remat)
+        return _gdn_norm(cfg, x, params["final_norm"])
     flags = layer_flags(cfg)
     fam = cfg.family
 
@@ -420,6 +446,107 @@ def loss_fn(params, cfg: ModelConfig, batch, ctx=None, *, remat: bool = True):
 
 
 # ---------------------------------------------------------------------------
+# Gated DeltaNet hybrid (gdn)
+# ---------------------------------------------------------------------------
+def _gdn_norm(cfg: ModelConfig, x, w):
+    return L.rmsnorm(x, w, cfg.norm_eps, cfg.norm_zero_centred)
+
+
+def _gdn_experts(bp, cfg: ModelConfig, x, stacks, layer):
+    h = _gdn_norm(cfg, x, bp["ln2"])
+    with jax.named_scope("moe"):
+        y, counters = L.moe_dropless(dict(bp["moe"], **stacks), cfg, h, layer)
+    return x + y, counters
+
+
+def _gdn_stack(params, cfg: ModelConfig, x, positions, cache=None, *,
+               remat: bool = False):
+    """The whole gdn stack over ``x``: from empty state when ``cache`` is
+    None (the prompt's K/V, convolution and recurrent state come back), or
+    one decode step on ``cache``.  Returns ``(x, (state, k, v,
+    counters))``: state stacked over the DeltaNet layers, K/V over the
+    full layers, the routing counters (n_layers, 3) in layer order.
+
+    One scan step is one period; its DeltaNet layers are unrolled and read
+    their weights from the whole stack by layer index, and the expert
+    stacks stay whole too: a weight slice then feeds its products
+    directly, where a nested scan would copy each period's slice first."""
+    per = cfg.full_attn_every - 1
+    n_per = cfg.n_full_layers
+    experts = {}
+    blocks = {}
+    for kind in ("lin", "full"):
+        moe = dict(params["blocks"][kind]["moe"])
+        experts[kind] = {n: moe.pop(n) for n in ("wg", "wu", "wd")}
+        blocks[kind] = dict(params["blocks"][kind], moe=moe)
+    decoding = cache is not None
+    states = kbuf = vbuf = None
+    if decoding:
+        states = {n: cache[n].reshape((n_per, per) + cache[n].shape[1:])
+                  for n in ("conv", "state")}
+        kbuf, vbuf = cache["k"], cache["v"]
+
+    def lin_layer(x, st, layer):
+        bp = jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+                          blocks["lin"])
+        h = _gdn_norm(cfg, x, bp["ln1"])
+        with jax.named_scope("gdn"):
+            if decoding:
+                out, st = G.gdn_decode(bp["gdn"], cfg, h, st)
+            else:
+                out, st = G.gdn_prefill(bp["gdn"], cfg, h)
+        x, counters = _gdn_experts(bp, cfg, x + out, experts["lin"], layer)
+        return x, st, counters
+
+    def period(x, scanned):
+        fp, st, ck, cv, i = scanned
+        new_st, counts = [], []
+        for j in range(per):
+            st_j = None if st is None else jax.tree.map(lambda a: a[j], st)
+            x, st_j, c = lin_layer(x, st_j, i * per + j)
+            new_st.append(st_j)
+            counts.append(c)
+        h = _gdn_norm(cfg, x, fp["ln1"])
+        with jax.named_scope("gated_attn"):
+            if decoding:
+                out, kv = L.attention(fp["attn"], cfg, h, cache={"k": ck, "v": cv},
+                                      cache_index=cache["index"])
+            else:
+                out, kv = L.attention(fp["attn"], cfg, h, positions=positions)
+        x, c = _gdn_experts(fp, cfg, x + out, experts["full"], i)
+        new_st = jax.tree.map(lambda *a: jnp.stack(a), *new_st)
+        return x, (new_st, kv["k"], kv["v"], jnp.stack(counts + [c]))
+
+    x, (st, ks, vs, counters) = lax.scan(
+        _maybe_remat(period, remat), x,
+        (blocks["full"], states, kbuf, vbuf, jnp.arange(n_per, dtype=jnp.int32)))
+    st = jax.tree.map(lambda a: a.reshape((n_per * per,) + a.shape[2:]), st)
+    return x, (st, ks, vs, counters.reshape(cfg.n_layers, -1))
+
+
+def _gdn_prefill(params, cfg: ModelConfig, tokens, ctx, max_len: int):
+    B, Sq = tokens.shape
+    cache = zeros_cache(cfg, B, max_len)
+    x = embed_lookup(params["embed"]["table"], tokens, ctx)
+    x, (st, ks, vs, counters) = _gdn_stack(params, cfg, x, jnp.arange(Sq)[None, :])
+    cache.update(st)
+    for name, new in (("k", ks), ("v", vs)):
+        cache[name] = lax.dynamic_update_slice(cache[name], new.astype(cache[name].dtype),
+                                               (0, 0, 0, 0, 0))
+    cache["index"] = jnp.int32(Sq)
+    h = _gdn_norm(cfg, x[:, -1:], params["final_norm"])
+    return cache, logits_from_hidden(params, cfg, h), counters
+
+
+def _gdn_decode(params, cfg: ModelConfig, cache, tokens, ctx):
+    x = embed_lookup(params["embed"]["table"], tokens, ctx)
+    x, (st, ks, vs, counters) = _gdn_stack(params, cfg, x, None, cache)
+    new_cache = dict(cache, k=ks, v=vs, index=cache["index"] + 1, **st)
+    h = _gdn_norm(cfg, x, params["final_norm"])
+    return new_cache, logits_from_hidden(params, cfg, h), counters
+
+
+# ---------------------------------------------------------------------------
 # decode caches
 # ---------------------------------------------------------------------------
 def cache_struct(cfg: ModelConfig, batch: int, max_len: int,
@@ -427,6 +554,14 @@ def cache_struct(cfg: ModelConfig, batch: int, max_len: int,
     fam = cfg.family
     dt = cfg.jdtype
     caches: Dict[str, Any] = {}
+    if fam == "gdn":
+        kv = jax.ShapeDtypeStruct(
+            (cfg.n_full_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim), dt)
+        n_lin = cfg.n_layers - cfg.n_full_layers
+        caches = {n: jax.ShapeDtypeStruct((n_lin,) + v.shape, v.dtype)
+                  for n, v in G.state_struct(cfg, batch).items()}
+        caches.update(k=kv, v=kv, index=jax.ShapeDtypeStruct((), jnp.int32))
+        return caches
     if fam in ("dense", "moe", "encdec", "vlm", "hybrid"):
         kv = jax.ShapeDtypeStruct(
             (n_attn_slots(cfg), batch, max_len, cfg.n_kv_heads, cfg.head_dim), dt)
@@ -494,10 +629,19 @@ def cache_pspecs(cfg: ModelConfig, ctx):
 # ---------------------------------------------------------------------------
 # prefill
 # ---------------------------------------------------------------------------
-def prefill(params, cfg: ModelConfig, batch, ctx=None, max_len: int = 0):
+def prefill(params, cfg: ModelConfig, batch, ctx=None, max_len: int = 0, *,
+            routing: bool = False):
+    """Returns ``(cache, last logits)``; with ``routing`` (gdn family only)
+    also the routing counters of every expert layer, (n_layers, 3) in
+    ``layers.ROUTING_COUNTERS`` order."""
     tokens = batch["tokens"]
     B, Sq = tokens.shape
     max_len = max_len or Sq + 1
+    if cfg.family == "gdn":
+        out = _gdn_prefill(params, cfg, tokens, ctx, max_len)
+        return out if routing else out[:2]
+    if routing:
+        raise ValueError(f"{cfg.name}: routing counters need a dropless expert family")
     n_patches = 0
     if cfg.family == "vlm":
         n_patches = batch["patches"].shape[1]
@@ -576,8 +720,15 @@ def prefill(params, cfg: ModelConfig, batch, ctx=None, max_len: int = 0):
 # ---------------------------------------------------------------------------
 # decode
 # ---------------------------------------------------------------------------
-def decode_step(params, cfg: ModelConfig, cache, tokens: jnp.ndarray, ctx=None):
-    """One decode step.  tokens: (B, 1).  Returns (new_cache, logits)."""
+def decode_step(params, cfg: ModelConfig, cache, tokens: jnp.ndarray, ctx=None, *,
+                routing: bool = False):
+    """One decode step.  tokens: (B, 1).  Returns (new_cache, logits), and
+    the routing counters with ``routing`` (see :func:`prefill`)."""
+    if cfg.family == "gdn":
+        out = _gdn_decode(params, cfg, cache, tokens, ctx)
+        return out if routing else out[:2]
+    if routing:
+        raise ValueError(f"{cfg.name}: routing counters need a dropless expert family")
     x = embed_lookup(params["embed"]["table"], tokens, ctx)
     idx = cache["index"]
     fam = cfg.family

@@ -86,7 +86,7 @@ def test_smoke_prefill_decode(arch):
 
 
 @pytest.mark.parametrize("arch", ["deepseek-67b", "mamba2-2.7b", "zamba2-7b",
-                                  "gemma3-12b"])
+                                  "gemma3-12b", "qwen3-next-80b-a3b"])
 def test_decode_matches_forward(arch):
     """Teacher-forced decode must reproduce the forward pass logits: run
     prefill on s tokens, then decode the next token and compare with the
