@@ -12,7 +12,7 @@ vocabulary are shared by the offline :class:`~repro.core.simulator.Simulator`
 The flight recorder additionally emits *point* events (``EV_*`` below):
 raw timestamped markers (task start/end, steal attempt/hit, gang
 reserve/enter/exit, frame suspend/wake/resume, plain-body block/unblock,
-deadlock polls, worker park/wake, replay deviations) that
+deadlock polls, worker park/wake, replay deviations, batched launches) that
 :mod:`repro.obs.trace` assembles into the span kinds above.
 """
 
@@ -64,6 +64,9 @@ EV_RUN_AHEAD = "run_ahead"            # a=tid
 EV_RESOURCE_ACQUIRE = "resource_acquire"  # a=tid, b=n_res   label=task name
 EV_RESOURCE_WAIT = "resource_wait"    # a=tid (task deferred on contention)
 EV_RESOURCE_RELEASE = "resource_release"  # a=tid, b=n_res
+# one device program launched for a batch of ready sibling tasks; their
+# task_start events follow it at the same timestamp
+EV_BATCH = "batch"                    # a=tasks in the launch
 # host phases of the caller (engine step, Session.run): begin/end pairs on
 # the external ring, a=thread ident, label=constant phase name
 EV_PHASE_BEGIN = "phase_begin"
@@ -76,7 +79,7 @@ EVENT_KINDS = frozenset({
     EV_BLOCK, EV_UNBLOCK, EV_DEADLOCK_POLL, EV_PARK, EV_WAKE,
     EV_REPLAY_FALLBACK, EV_REPLAY_STALL, EV_REPLAY_SKIP, EV_RUN_AHEAD,
     EV_RESOURCE_ACQUIRE, EV_RESOURCE_WAIT, EV_RESOURCE_RELEASE,
-    EV_PHASE_BEGIN, EV_PHASE_END,
+    EV_BATCH, EV_PHASE_BEGIN, EV_PHASE_END,
 })
 
 

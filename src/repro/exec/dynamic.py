@@ -35,6 +35,14 @@ hanging.  With recording on, every yield point suspends (no inline fast
 path) so each resume segment lands in the run lists as a
 :class:`~repro.core.taskgraph.FrameResume` entry and replay can reproduce
 the exact frame interleaving.
+
+Batched launch of ready siblings: a worker that takes a task carrying a
+``jit_safe`` :class:`~repro.compile.fuse.FuseSpec` (a pure kernel over the
+graph's ``fuse_state``) also takes the ready tasks next to it at the same
+end of the same deque that share its kernel, arity and priority, and runs
+them as one jitted program.  Tasks in a deque are ready, so these are
+exactly tasks that two workers may run at once; each still completes, is
+recorded and is traced one by one.
 """
 
 from __future__ import annotations
@@ -46,6 +54,7 @@ from collections import deque
 from types import GeneratorType
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
+from ..compile.fuse import batch_counts, batch_limit, batched_program, fuse_spec_of
 from ..core.gang import GangState, is_eligible_to_sched
 from ..core.policies import make_policy
 from ..core.simulator import DeadlockError
@@ -156,6 +165,8 @@ class DynamicDispatch(DispatchStrategy):
         self._contexts: List[List[Tuple[int, int]]] = [[] for _ in range(n_workers)]
 
         self._graph: Optional[TaskGraph] = None
+        # the graph's fuse_state: batched launches read and write it
+        self._fuse_state: Any = None
         self._indeg: List[int] = []
         self._indeg_lock = threading.Lock()
         self._results: Dict[int, Any] = {}
@@ -180,7 +191,8 @@ class DynamicDispatch(DispatchStrategy):
 
         # always-on lightweight run counters (surfaced in RunReport.stats)
         self.run_stats: Dict[str, int] = {
-            "steals": 0, "steal_attempts": 0, "frame_suspends": 0}
+            "steals": 0, "steal_attempts": 0, "frame_suspends": 0,
+            "launches": 0, "batched_tasks": 0}
 
     # ------------------------------------------------------------------
     # DispatchStrategy interface
@@ -189,6 +201,7 @@ class DynamicDispatch(DispatchStrategy):
 
     def begin_run(self, graph: TaskGraph) -> None:
         self._graph = graph
+        self._fuse_state = getattr(graph, "fuse_state", None)
         self._indeg = graph.indegrees()
         self._results = {}
         self._remaining = len(graph)
@@ -228,7 +241,8 @@ class DynamicDispatch(DispatchStrategy):
             self._rec_wait_choices = {}
         self.run_stats = {"steals": 0, "steal_attempts": 0,
                           "frame_suspends": 0, "resource_acquires": 0,
-                          "resource_waits": 0, "resource_releases": 0}
+                          "resource_waits": 0, "resource_releases": 0,
+                          "launches": 0, "batched_tasks": 0}
         self.arbiter.begin(graph)
         self.recorder.begin_run()
         # master thread (worker 0's queue) receives the roots
@@ -329,7 +343,42 @@ class DynamicDispatch(DispatchStrategy):
         with self._local_locks[w]:
             self._locals[w].append(task)
 
-    def _pop_local(self, w: int) -> Optional[Task]:
+    def _batch_key(self, task: Task) -> Any:
+        """What a sibling must share for ``task`` and it to launch as one
+        program, or ``None`` if ``task`` runs alone: a ``jit_safe`` spec
+        over the graph's ``fuse_state``, no resources, no region."""
+        if self._fuse_state is None:
+            return None
+        spec = fuse_spec_of(task)
+        if (spec is None or not spec.jit_safe or task.parallel is not None
+                or task.uses or task.uses_shared):
+            return None
+        return (spec.fn, len(spec.reads), len(spec.writes), task.priority)
+
+    def _take_run(self, dq: Deque[Task], i: int, step: int) -> List[Task]:
+        """Remove ``dq[i]`` and the siblings after it in direction ``step``
+        (-1 toward the FIFO end, +1 toward the LIFO end) that share its
+        batch key, at most :func:`batch_limit` tasks; caller holds the lock."""
+        task = dq[i]
+        key = self._batch_key(task)
+        j = i
+        if key is not None:
+            state = self._fuse_state
+            limit = batch_limit([state[k] for k in fuse_spec_of(task).reads])
+            end = -1 if step < 0 else len(dq)
+            while (abs(j - i) + 1 < limit and j + step != end
+                   and self._batch_key(dq[j + step]) == key):
+                j += step
+        if j == i:
+            del dq[i]
+            return [task]
+        lo, hi = min(i, j), max(i, j)
+        run = [dq[k] for k in range(i, j + step, step)]
+        for _ in range(hi - lo + 1):
+            del dq[lo]
+        return run
+
+    def _pop_local(self, w: int) -> Optional[List[Task]]:
         with self._local_locks[w]:
             dq = self._locals[w]
             if not dq:
@@ -339,25 +388,21 @@ class DynamicDispatch(DispatchStrategy):
             for i in range(len(dq) - 1, max(-1, len(dq) - 9), -1):
                 if dq[i].priority > best_p:
                     best_i, best_p = i, dq[i].priority
-            t = dq[best_i]
-            del dq[best_i]
-            return t
+            return self._take_run(dq, best_i, -1)
 
-    def _steal_local(self, victim: int) -> Optional[Task]:
+    def _steal_local(self, victim: int) -> Optional[List[Task]]:
         with self._local_locks[victim]:
             dq = self._locals[victim]
             if not dq:
                 return None
             if not self.arbiter.active:
-                return dq.popleft()
+                return self._take_run(dq, 0, 1)
             # conflict-aware: don't burn the steal on a task whose resources
             # are currently held — it would only bounce into the arbiter's
             # wait list (bounded FIFO-end scan, mirrors the priority pop)
             for i in range(min(len(dq), 8)):
                 if not self.arbiter.would_defer(dq[i].tid):
-                    t = dq[i]
-                    del dq[i]
-                    return t
+                    return self._take_run(dq, i, 1)
             return None
 
     def _pop_resume(self, victim: int) -> Optional[TaskFrame]:
@@ -396,9 +441,9 @@ class DynamicDispatch(DispatchStrategy):
         if frame is not None:
             self._run_frame_segment(w, frame)
             return True
-        task = self._pop_local(w)
-        if task is not None:
-            self._run_task(w, task)
+        run = self._pop_local(w)
+        if run is not None:
+            self._run_tasks(w, run)
             return True
         # work stealing (Algorithm 2 policy)
         pol = self._policies[w]
@@ -423,7 +468,8 @@ class DynamicDispatch(DispatchStrategy):
             elif isinstance(got, TaskFrame):
                 entry = FrameResume(got.task.tid, got.resumes + 1)
             else:
-                entry = got.tid
+                entry = None
+                self._rec_steals[w].extend((victim, t.tid) for t in got)
             if entry is not None:
                 self._rec_steals[w].append((victim, entry))
         if isinstance(got, _GangULT):
@@ -434,7 +480,7 @@ class DynamicDispatch(DispatchStrategy):
             self._run_frame_segment(w, got)
         else:
             self.recorder.emit(w, EV_STEAL_HIT, "task", victim)
-            self._run_task(w, got)
+            self._run_tasks(w, got)
         return True
 
     # ------------------------------------------------------------------
@@ -444,6 +490,62 @@ class DynamicDispatch(DispatchStrategy):
 
     def _end_unit(self, w: int) -> None:
         self._depth[w] -= 1
+
+    def _run_tasks(self, w: int, run: List[Task]) -> None:
+        if len(run) == 1:
+            self._run_task(w, run[0])
+        else:
+            self._run_batch(w, run)
+
+    def _run_batch(self, w: int, run: List[Task]) -> None:
+        """Launch a run of ready siblings (:meth:`_take_run`) as one jitted
+        program per power-of-two share of it (:func:`batch_counts`), then
+        complete each task as :meth:`_run_task` would: result, successors,
+        recording entry, and a start/end pair around its launch."""
+        state = self._fuse_state
+        specs = [fuse_spec_of(t) for t in run]
+        fn, n_reads, n_writes = specs[0].fn, len(specs[0].reads), len(specs[0].writes)
+        rec = self.recorder
+        stats = self.run_stats
+        i = 0
+        self._begin_unit(w)
+        try:
+            for count in batch_counts(len(run)):
+                if self.core.aborted:
+                    return
+                tasks, chunk = run[i:i + count], specs[i:i + count]
+                i += count
+                args = [state[k] for spec in chunk for k in spec.reads]
+                rec.emit_batch_start(w, tasks)
+                if self._recording:
+                    self._rec_entries[w].extend(t.tid for t in tasks)
+                    comms = [t.tid for t in tasks if t.kind == "comm"]
+                    if comms:
+                        with self._rec_comm_lock:
+                            self._rec_comms.extend(comms)
+                try:
+                    prog = batched_program(fn, n_reads, n_writes, count,
+                                           args[:n_reads])
+                    outs = prog(*args)
+                except BaseException as e:  # noqa: BLE001 - propagate to run()
+                    self.core.fail(e)
+                    return
+                stats["launches"] += 1
+                stats["batched_tasks"] += count
+                outs = iter(outs)
+                for spec in chunk:
+                    for k in spec.writes:
+                        state[k] = next(outs)
+                rec.emit_batch_end(w, tasks)
+                with self._results_lock:
+                    for task, spec in zip(tasks, chunk):
+                        self._results[task.tid] = (
+                            state[spec.result_key]
+                            if spec.result_key is not None else None)
+                for task in tasks:
+                    self._complete(w, task)
+        finally:
+            self._end_unit(w)
 
     def _run_task(self, w: int, task: Task) -> None:
         arbiter = self.arbiter
@@ -474,7 +576,10 @@ class DynamicDispatch(DispatchStrategy):
         self._begin_unit(w)
         try:
             try:
-                result = task.fn(ctx) if task.fn is not None else None
+                result = None
+                if task.fn is not None:
+                    self.run_stats["launches"] += 1
+                    result = task.fn(ctx)
             except BaseException as e:  # noqa: BLE001 - propagate to run()
                 self.core.fail(e)
                 return

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -45,10 +46,12 @@ class _QrFuseState:
             self.store[k] = v
 
 
+@jax.jit
 def _qr_col_fused(vt, *tiles):
     """Fused trailing-column update ``A_j <- (I - V T V^T)^T A_j`` over the
-    stacked tiles of block column ``j``.  Module-level so compiled plans
-    cache one jitted callable per column shape."""
+    stacked tiles of block column ``j``.  One program, launched by the
+    column task's body; module-level so that compiled plans and batched
+    launches cache their programs on it."""
     V, T = vt
     b = tiles[0].shape[0]
     a = jnp.concatenate(tiles, axis=0)
@@ -98,11 +101,10 @@ def build_qr_graph(
 
     def col_body(j: int, k: int):
         def fn(ctx):
-            V, T = vt_store[k]
-            a = jnp.concatenate([store[(i, j)] for i in range(k, store.nb)], axis=0)
-            a = a - V @ (T.T @ (V.T @ a))
-            for idx, i in enumerate(range(k, store.nb)):
-                store[(i, j)] = a[idx * store.b:(idx + 1) * store.b]
+            rows = range(k, store.nb)
+            out = _qr_col_fused(vt_store[k], *[store[(i, j)] for i in rows])
+            for i, tile in zip(rows, out if len(rows) > 1 else (out,)):
+                store[(i, j)] = tile
         return fn if numeric else None
 
     def col_fuse(j: int, k: int):

@@ -36,8 +36,9 @@ import weakref
 from time import perf_counter
 from typing import List, NamedTuple, Optional, Tuple
 
-from ..core.tracing import (EV_FRAME_RESUME, EV_FRAME_SUSPEND, EV_PHASE_BEGIN,
-                            EV_PHASE_END, EV_TASK_START)
+from ..core.tracing import (EV_BATCH, EV_FRAME_RESUME, EV_FRAME_SUSPEND,
+                            EV_PHASE_BEGIN, EV_PHASE_END, EV_TASK_END,
+                            EV_TASK_START)
 from .trace import RuntimeTrace, assemble
 
 __all__ = ["FlightRecorder", "NullRecorder", "NULL_RECORDER", "Window",
@@ -124,6 +125,12 @@ class NullRecorder:
     def emit_resource(self, worker, kind, task, n_res=0):
         return None
 
+    def emit_batch_start(self, worker, tasks):
+        return None
+
+    def emit_batch_end(self, worker, tasks):
+        return None
+
     def phase_begin(self, label):
         return None
 
@@ -192,6 +199,20 @@ class FlightRecorder:
         """Resource acquire/wait/release for ``task`` (kind is one of the
         EV_RESOURCE_* constants; label building stays off the null path)."""
         self.emit(worker, kind, task.name, task.tid, n_res)
+
+    # -- a batched launch, on a worker's ring: every task's start (end)
+    # carries one timestamp, so the assembled trace shows the launch as one
+    # span and the gaps between spans stay the gaps between launches -------
+    def emit_batch_start(self, worker, tasks):
+        ring, t = self.rings[worker], perf_counter()
+        ring.append((t, EV_BATCH, "", len(tasks), -1))
+        for task in tasks:
+            ring.append((t, EV_TASK_START, task.kind + "|" + task.name, task.tid, 0))
+
+    def emit_batch_end(self, worker, tasks):
+        ring, t = self.rings[worker], perf_counter()
+        for task in tasks:
+            ring.append((t, EV_TASK_END, "", task.tid, -1))
 
     # -- host phases of the caller (``engine.step``, ``session.run``, ...):
     # begin/end point events on the external ring, tagged with the calling

@@ -30,6 +30,7 @@ from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Tuple
 from ..core.tracing import (
     EV_BARRIER_DONE,
     EV_BARRIER_WAIT,
+    EV_BATCH,
     EV_BLOCK,
     EV_DEADLOCK_POLL,
     EV_FRAME_RESUME,
@@ -83,7 +84,11 @@ _COUNTER_EVENTS = {
     "resource_acquires": EV_RESOURCE_ACQUIRE,
     "resource_waits": EV_RESOURCE_WAIT,
     "resource_releases": EV_RESOURCE_RELEASE,
+    "batch_launches": EV_BATCH,
 }
+#: every counter: those above, and ``batched_tasks``, the sum of the
+#: batch events' sizes
+_COUNTERS = (*_COUNTER_EVENTS, "batched_tasks")
 
 
 def _split_label(label: str) -> Tuple[str, str]:
@@ -146,7 +151,7 @@ class RuntimeTrace(Trace):
         value)}`` for every shared counter that disagrees (empty == the
         trace accounts for every counted event)."""
         out: Dict[str, Tuple[int, int]] = {}
-        for key in _COUNTER_EVENTS:
+        for key in _COUNTERS:
             if key in stats and key in self.counters:
                 if int(stats[key]) != self.counters[key]:
                     out[key] = (int(stats[key]), self.counters[key])
@@ -266,7 +271,7 @@ def assemble(snapshot: List[Tuple[int, float, str, str, int, int]],
     events = sorted((e for e in snapshot if e[2] not in _PHASES),
                     key=lambda e: e[1])
     if not events:
-        rt.counters = {k: 0 for k in _COUNTER_EVENTS}
+        rt.counters = {k: 0 for k in _COUNTERS}
         return rt
     t_base = events[0][1]
     rt.t_base = t_base
@@ -335,7 +340,9 @@ def assemble(snapshot: List[Tuple[int, float, str, str, int, int]],
         for cname, ckind in _COUNTER_EVENTS.items():
             if ev == ckind:
                 counters[cname] += 1
-        if ev == EV_RESOURCE_WAIT:
+        if ev == EV_BATCH:
+            counters["batched_tasks"] += a
+        elif ev == EV_RESOURCE_WAIT:
             res_pending[a] = t
         elif ev == EV_RESOURCE_ACQUIRE:
             t0 = res_pending.pop(a, None)
@@ -360,7 +367,7 @@ def assemble(snapshot: List[Tuple[int, float, str, str, int, int]],
 
     spans.sort(key=lambda e: (e.t0, e.worker, e.t1))
     rt.events = spans
-    for k in _COUNTER_EVENTS:
+    for k in _COUNTERS:
         counters.setdefault(k, 0)
     rt.counters = dict(counters)
     rt.steal_victims = victims
